@@ -33,20 +33,19 @@ bench-kernels:
 	$(PYTHON) benchmarks/kernel_bench.py
 
 # Workload-subsystem sweep: protocol x workload x n_agents grid plus the
-# donation and packed-metadata A/Bs -> BENCH_workloads.json
+# in-process engine A/Bs -> BENCH_workloads.json
 # (schema: benchmarks/SCHEMA.md)
 sweep:
 	$(PYTHON) -m repro.workloads.sweep --out BENCH_workloads.json
 
-# CI smoke: 1 replica, n_agents=16 grid, no subprocess A/Bs — catches
+# CI smoke: 1 replica, n_agents=16 grid, no serving cells — catches
 # sweep-schema regressions in PR instead of at bench time.  Runs under
 # REPRO_TRACE=1 so the schema-v6 latency columns and the Perfetto export
 # are exercised too; benchmarks/check_smoke.py carries the structural
 # assertions.  The committed BENCH_workloads.json comes from `make sweep`.
 sweep-smoke:
 	env REPRO_TRACE=1 $(PYTHON) -m repro.workloads.sweep --sizes 16 \
-	  --seeds 1 --iters 1 --no-donation --no-pack-ab \
-	  --remote-batch-sizes 16 --no-fuse-ab --no-serving \
+	  --seeds 1 --iters 1 --remote-batch-sizes 16 --no-fuse-ab --no-serving \
 	  --out BENCH_workloads.smoke.json --trace-out TRACE_sweep.json
 	$(PYTHON) benchmarks/check_smoke.py BENCH_workloads.smoke.json \
 	  --expect-trace
@@ -69,8 +68,7 @@ trace:
 # diff with --advisory.
 bench-compare:
 	env REPRO_TRACE=1 $(PYTHON) -m repro.workloads.sweep --sizes 16 \
-	  --seeds 1 --iters 1 --no-donation --no-pack-ab \
-	  --remote-batch-sizes 16 --no-fuse-ab --no-serving \
+	  --seeds 1 --iters 1 --remote-batch-sizes 16 --no-fuse-ab --no-serving \
 	  --out BENCH_workloads.smoke.new.json --trace-out TRACE_sweep.new.json
 	$(PYTHON) benchmarks/compare.py BENCH_workloads.smoke.json \
 	  BENCH_workloads.smoke.new.json

@@ -15,6 +15,8 @@ import time
 
 
 def main():
+    from repro.runtime import compile_cache
+    compile_cache.enable()
     import jax
     import jax.numpy as jnp
     import numpy as np

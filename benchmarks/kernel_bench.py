@@ -104,6 +104,8 @@ def rows():
 
 def main():
     from repro.kernels import common
+    from repro.runtime import compile_cache
+    compile_cache.enable()
     # mode is chosen once per process; an interpret-mode benchmark is a
     # user error (REPRO_KERNEL_MODE=interpret) and warns loudly
     print(f"# kernel_mode={common.note_benchmark('kernel_bench')}")

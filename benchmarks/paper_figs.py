@@ -125,6 +125,8 @@ def scaling_sweep(out_dir: str = "artifacts/paper"):
 
 
 def main(n_wgs: int = 16):
+    from repro.runtime import compile_cache
+    compile_cache.enable()
     print(f"[paper figs] scenarios x apps at {n_wgs} work-groups")
     results = run_all(n_wgs=n_wgs)
     print("\nFig4 speedup over Baseline:")

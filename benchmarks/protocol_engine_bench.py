@@ -13,6 +13,12 @@ the same measurement runs against the old scan-based engine in a
 subprocess; speedup_vs_seed fields are then filled in.  The JSON committed
 with the refactor PR was produced this way against commit 9810f7e.
 
+Each configuration runs as its own top-level child process; this parent
+never imports JAX, so every child can take the device (a process that has
+touched JAX holds the chip, and a child then cannot reach it).  The
+children share the persistent compilation cache: JAX_COMPILATION_CACHE_DIR
+if set, else `<checkout>/.jax_cache` (repro.runtime.compile_cache).
+
 Usage:
   PYTHONPATH=src python benchmarks/protocol_engine_bench.py \
       [--apps pagerank] [--scenarios srsp rsp] [--sizes 16 64 256] \
@@ -32,8 +38,7 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "src"))
 
-import jax
-import jax.numpy as jnp
+from repro.runtime import compile_cache  # noqa: E402  (imports no JAX)
 
 
 # shape of one benchmark configuration, shared with the seed subprocess
@@ -95,6 +100,7 @@ print(json.dumps({
     "events_per_s": round(events / (iters + 1) / steady, 1),
     "proc_errors": errors,
     "makespan": float(jnp.max(c.cycles)),
+    "backend": jax.default_backend(),
 }))
 """
 
@@ -107,6 +113,7 @@ def measure(app, scenario, n_wgs, iters, engine, seed_src=None):
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     src = seed_src if engine == "seed" else os.path.join(root, "src")
     env["PYTHONPATH"] = os.path.abspath(src)
+    env.setdefault("JAX_COMPILATION_CACHE_DIR", compile_cache.DEFAULT_DIR)
     out = subprocess.run(
         [sys.executable, "-c", _MEASURE_SNIPPET, app, scenario, str(n_wgs),
          str(n_chunks), str(graph_n), str(iters), engine],
@@ -197,7 +204,7 @@ def main():
         "metric_note": "speedups compare steady-state wall-clock per "
                        "simulator iteration (run_app minus one-time jit "
                        "compile); compile_s is reported separately per run",
-        "backend": jax.default_backend(),
+        "backend": runs[0]["backend"] if runs else None,
         "config": {"apps": args.apps, "scenarios": args.scenarios,
                    "sizes": args.sizes, "iters": args.iters,
                    "seed_src": args.seed_src},

@@ -29,9 +29,11 @@ def main():
     paper_figs.main(8 if quick else 16)
 
     section("sRSP cross-pod selective delta sync (framework layer)")
+    # a simulated 8-device pod axis on host CPUs: the child never needs
+    # the accelerator this process may already hold
     env = dict(os.environ,
                XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH="src")
+               JAX_PLATFORMS="cpu", PYTHONPATH="src")
     subprocess.run([sys.executable, "-m", "benchmarks.delta_sync_bench"],
                    env=env, check=True)
 

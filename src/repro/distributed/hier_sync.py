@@ -28,18 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """Version-compat shim: `jax.shard_map` (new API, `check_vma` kwarg)
-    landed after 0.4.x; fall back to `jax.experimental.shard_map.shard_map`
-    (old API, `check_rep` kwarg) on installed versions that lack it."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
 from repro.distributed import compress as CMP
 from repro.kernels.selective_flush.ref import (selective_flush_ref,
                                                selective_apply_ref)
@@ -157,9 +145,9 @@ def make_pod_sync(mesh: Mesh, n_blocks: int, block_size: int,
         bytes_selective=P("pod"), bytes_full=P("pod"))
 
     @functools.partial(
-        _shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P("pod", None, None), state_specs),
-        out_specs=(P("pod", None, None), state_specs))
+        out_specs=(P("pod", None, None), state_specs), check_vma=False)
     def sync(bank_stacked, st_stacked):
         bank = bank_stacked[0]
         st = jax.tree.map(lambda x: x[0], st_stacked)

@@ -7,19 +7,21 @@ Two kernels, matching the two fusion surfaces of `ref.py`:
     batch rule with the future-first-remote fence, and (when the
     workload declares the remote-batching capability) the n×n address
     dedup of the co-schedulable remote batch.  Everything lives in VMEM
-    as [1, n] rows; reductions are branch-free min/where chains so the
-    VPU never leaves the kernel for a scheduling decision.
+    as [1, n] rows (plus [n, 1] column copies for the dedup matrix);
+    reductions are branch-free min/where chains so the VPU never leaves
+    the kernel for a scheduling decision.
 
-  * `plane_commit_pallas` — the packed wvalid/wdirty plane scatter of
-    `protocol.b_store_word`/`b_load` fused into one pass per lane: grid
-    over lanes, the (lane, block) row selected by a scalar-prefetched
-    index map (the `selective_flush` idiom), and the single-bit update
-    expanded IN REGISTER from the uint32 word-bitmask — build the lane
-    mask with a `broadcasted_iota` compare against `o >> 5` and OR the
-    `1 << (o & 31)` pattern in, reading the pre-op bit from the same
-    register (`core/bitmask.py` semantics; no unpacked plane ever
-    materializes).  Both planes are input/output-aliased so untouched
-    blocks stay in place.
+  * `plane_commit_pallas` — the wvalid/wdirty plane update of
+    `protocol.b_store_word`/`b_load` for every cache lane in one vector
+    pass: the planes are lane-dense [n, nb * K] int32 rows, each lane's
+    target flag is a (column, bit) pair, and a `broadcasted_iota`
+    compare builds the pattern plane that both reads the pre-op bits and
+    ORs the new ones (`core/bitmask.py` semantics; no unpacked plane
+    ever materializes).  Both planes are input/output-aliased.
+
+TPU tiling: every block is a whole array, so the (8, 128) block rule
+holds at any n, nb and W, alone and under `jax.vmap` (which adds a
+squeezed leading grid axis).
 
 The jnp references in `ref.py` are the CPU fast path AND the oracle the
 interpret-mode unit tests pin these kernels against
@@ -33,7 +35,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.fused_turn.ref import TripPlan
 
@@ -53,7 +54,8 @@ def _first_min(vals, mask, idx, n):
 
 
 def _plan_kernel(clocks_ref, can_l_ref, can_r_ref, bound_ref, raddr_ref,
-                 hor_ref, lmask_ref, rmask_ref, wg_ref, *, remote_cap):
+                 hor_ref, clocks_c_ref, can_r_c_ref, raddr_c_ref,
+                 lmask_ref, rmask_ref, wg_ref, *, remote_cap):
     n = clocks_ref.shape[-1]
     idx = lax.broadcasted_iota(jnp.int32, (1, n), 1)
     clocks = clocks_ref[...]
@@ -72,16 +74,23 @@ def _plan_kernel(clocks_ref, can_l_ref, can_r_ref, bound_ref, raddr_ref,
 
     if remote_cap:
         ml, jl = _first_min(clocks, can_l, idx, n)
-        lexr = (clocks < ml) | ((clocks == ml) & (idx < jl))
-        r0 = can_r & lexr & (clocks < hor)
-        raddr = raddr_ref[...]
-        ri, rj = raddr.reshape(n, 1), raddr.reshape(1, n)
-        ci, cj = clocks.reshape(n, 1), clocks.reshape(1, n)
-        ii, ij = idx.reshape(n, 1), idx.reshape(1, n)
-        r0i, r0j = r0.reshape(n, 1), r0.reshape(1, n)
-        collide = r0i & r0j & (ri == rj)
-        earlier = (cj < ci) | ((cj == ci) & (ij < ii))
-        rmask = r0 & ~jnp.any(collide & earlier, axis=1).reshape(1, n)
+
+        def first_remote(clk, cr, ix):
+            lexr = (clk < ml) | ((clk == ml) & (ix < jl))
+            return cr & lexr & (clk < hor)
+
+        # the n x n dedup is laid out [other j (sublanes), self i (lanes)]
+        # from the [n, 1] column copies of the inputs, so no in-kernel
+        # transpose is needed and the any() over j lands back on a row
+        r0 = first_remote(clocks, can_r, idx)
+        clk_c = clocks_c_ref[...]
+        idx_c = lax.broadcasted_iota(jnp.int32, (n, 1), 0)
+        r0_c = first_remote(clk_c, can_r_c_ref[...] != 0, idx_c)
+        collide = r0_c & r0 & (raddr_c_ref[...] == raddr_ref[...])
+        earlier = (clk_c < clocks) | ((clk_c == clocks) & (idx_c < idx))
+        lost = jnp.max((collide & earlier).astype(jnp.int32), axis=0,
+                       keepdims=True)
+        rmask = r0 & (lost == 0)
     else:
         rmask = jnp.zeros((1, n), bool)
 
@@ -98,9 +107,11 @@ def trip_plan_pallas(clocks, can_l, can_r, bound, raddr, horizon,
 
     Scalar `horizon` must be a concrete value (pass BIG for the plain
     engines' no-fence trips); `raddr` is ignored when remote_cap=False
-    (pass zeros)."""
+    (pass zeros).  The remote dedup also reads [n, 1] column copies of
+    clocks/can_r/raddr (an XLA reshape outside the kernel)."""
     n = clocks.shape[0]
     row = lambda x, dt: jnp.asarray(x, dt).reshape(1, n)
+    col = lambda x, dt: jnp.asarray(x, dt).reshape(n, 1)
     hor = jnp.asarray(horizon, jnp.float32).reshape(1, 1)
     lmask, rmask, wg = pl.pallas_call(
         functools.partial(_plan_kernel, remote_cap=remote_cap),
@@ -109,66 +120,68 @@ def trip_plan_pallas(clocks, can_l, can_r, bound, raddr, horizon,
                    jax.ShapeDtypeStruct((1, 1), jnp.int32)),
         interpret=interpret,
     )(row(clocks, jnp.float32), row(can_l, jnp.int32), row(can_r, jnp.int32),
-      row(bound, jnp.float32), row(raddr, jnp.int32), hor)
+      row(bound, jnp.float32), row(raddr, jnp.int32), hor,
+      col(clocks, jnp.float32), col(can_r, jnp.int32), col(raddr, jnp.int32))
     return TripPlan(lmask=lmask[0] != 0, rmask=rmask[0] != 0, wg=wg[0, 0])
 
 
-def _commit_kernel(b_ref, o_ref, sv_ref, sd_ref, wv_ref, wd_ref,
+def _commit_kernel(wv_ref, wd_ref, col_ref, bit_ref, sv_ref, sd_ref,
                    wv_out, wd_out, wasv_ref, wasd_ref):
-    i = pl.program_id(0)
-    L = wv_ref.shape[-1]
-    o = o_ref[i]
-    # in-register uint32 bitmask expansion (core/bitmask.py semantics):
-    # word o lives in lane o >> 5, bit o & 31 — one [1, L] pattern row,
-    # no unpacked plane
-    lanes = lax.broadcasted_iota(jnp.int32, (1, L), 1)
-    bit = jnp.uint32(1) << (o.astype(jnp.uint32) & jnp.uint32(31))
-    pattern = jnp.where(lanes == (o >> 5), bit, jnp.uint32(0))
-    rv = wv_ref[0, 0, :].reshape(1, L)
-    rd = wd_ref[0, 0, :].reshape(1, L)
-    wasv_ref[0] = jnp.any((rv & pattern) != 0).astype(jnp.int32)
-    wasd_ref[0] = jnp.any((rd & pattern) != 0).astype(jnp.int32)
-    mv = jnp.where(sv_ref[i] != 0, pattern, jnp.uint32(0))
-    md = jnp.where(sd_ref[i] != 0, pattern, jnp.uint32(0))
-    wv_out[0, 0, :] = (rv | mv).reshape(L)
-    wd_out[0, 0, :] = (rd | md).reshape(L)
+    """Both planes as lane-dense [n, C] rows, one row per cache lane:
+    lane i's flag is bit pattern `bit[i]` of column `col[i]`.  The
+    pattern plane is a broadcasted-iota compare, so every lane commits
+    in the same vector pass (no grid, no per-lane DMA)."""
+    cols = lax.broadcasted_iota(jnp.int32, wv_ref.shape, 1)
+    pattern = jnp.where(cols == col_ref[...], bit_ref[...], 0)
+    rv = wv_ref[...]
+    rd = wd_ref[...]
+    hit = lambda plane: jnp.max(((plane & pattern) != 0).astype(jnp.int32),
+                                axis=1, keepdims=True)
+    wasv_ref[...] = hit(rv)
+    wasd_ref[...] = hit(rd)
+    wv_out[...] = rv | jnp.where(sv_ref[...] != 0, pattern, 0)
+    wd_out[...] = rd | jnp.where(sd_ref[...] != 0, pattern, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def plane_commit_pallas(wvalid, wdirty, b, o, set_valid, set_dirty,
                         *, interpret: bool = False):
-    """Fused packed-plane commit; bitwise `ref.plane_commit_ref` on the
-    packed layout.  wvalid/wdirty [n, nb, L] uint32; b/o [n] i32;
-    set_valid/set_dirty [n] bool.  Grid over lanes, the target (lane,
-    block) row DMA-selected by the scalar-prefetched block index (every
-    (lane, b) pair is distinct, so steps never collide); both planes
-    aliased in place.  Returns (wvalid', wdirty', was_valid, was_dirty)."""
-    n, nb, L = wvalid.shape
+    """Fused metadata-plane commit; bitwise `ref.plane_commit_ref`.
+
+    wvalid/wdirty [n, nb, L] uint32 packed or [n, nb, W] bool; b/o [n]
+    i32; set_valid/set_dirty [n] bool.  The planes enter the kernel as
+    int32 [n, nb * K] rows (a bitcast or a 0/1 cast, then a reshape):
+    lane i's flag sits in column b*L + (o >> 5) at bit 1 << (o & 31)
+    (packed, `core/bitmask.py` semantics) or in column b*W + o at bit 1
+    (boolean).  Whole-array VMEM blocks, both planes aliased in place.
+    Returns (wvalid', wdirty', was_valid, was_dirty)."""
+    n, nb, k = wvalid.shape
+    packed = wvalid.dtype != jnp.bool_
     b32 = jnp.clip(jnp.asarray(b, jnp.int32), 0, nb - 1)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(n,),
-        in_specs=[
-            pl.BlockSpec((1, 1, L), lambda i, b, o, sv, sd: (i, b[i], 0)),
-            pl.BlockSpec((1, 1, L), lambda i, b, o, sv, sd: (i, b[i], 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, 1, L), lambda i, b, o, sv, sd: (i, b[i], 0)),
-            pl.BlockSpec((1, 1, L), lambda i, b, o, sv, sd: (i, b[i], 0)),
-            pl.BlockSpec((1,), lambda i, b, o, sv, sd: (i,)),
-            pl.BlockSpec((1,), lambda i, b, o, sv, sd: (i,)),
-        ),
-    )
+    o32 = jnp.asarray(o, jnp.int32)
+    if packed:
+        col = b32 * k + (o32 >> 5)
+        bit = lax.bitcast_convert_type(
+            jnp.uint32(1) << (o32.astype(jnp.uint32) & jnp.uint32(31)),
+            jnp.int32)
+        to_rows = lambda p: lax.bitcast_convert_type(p, jnp.int32) \
+            .reshape(n, nb * k)
+        back = lambda r: lax.bitcast_convert_type(r, jnp.uint32) \
+            .reshape(n, nb, k)
+    else:
+        col = b32 * k + o32
+        bit = jnp.ones((n,), jnp.int32)
+        to_rows = lambda p: p.astype(jnp.int32).reshape(n, nb * k)
+        back = lambda r: (r != 0).reshape(n, nb, k)
+    lane_col = lambda x: jnp.asarray(x, jnp.int32).reshape(n, 1)
     wv2, wd2, wasv, wasd = pl.pallas_call(
         _commit_kernel,
-        grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((n, nb, L), jnp.uint32),
-                   jax.ShapeDtypeStruct((n, nb, L), jnp.uint32),
-                   jax.ShapeDtypeStruct((n,), jnp.int32),
-                   jax.ShapeDtypeStruct((n,), jnp.int32)),
-        input_output_aliases={4: 0, 5: 1},
+        out_shape=(jax.ShapeDtypeStruct((n, nb * k), jnp.int32),
+                   jax.ShapeDtypeStruct((n, nb * k), jnp.int32),
+                   jax.ShapeDtypeStruct((n, 1), jnp.int32),
+                   jax.ShapeDtypeStruct((n, 1), jnp.int32)),
+        input_output_aliases={0: 0, 1: 1},
         interpret=interpret,
-    )(b32, jnp.asarray(o, jnp.int32),
-      jnp.asarray(set_valid, jnp.int32), jnp.asarray(set_dirty, jnp.int32),
-      wvalid, wdirty)
-    return wv2, wd2, wasv != 0, wasd != 0
+    )(to_rows(wvalid), to_rows(wdirty), lane_col(col), lane_col(bit),
+      lane_col(set_valid), lane_col(set_dirty))
+    return back(wv2), back(wd2), wasv[:, 0] != 0, wasd[:, 0] != 0

@@ -7,9 +7,10 @@ the fast path and the oracle — interpret-mode Pallas is reserved for the
 kernel equivalence tests, never a silent benchmark path
 (`kernels/common.py`).
 
-`plane_commit` additionally falls back to the reference for the boolean
-(REPRO_NO_PACK=1) metadata layout: the packed uint32 planes are the TPU
-production layout (DESIGN.md §8), the boolean planes a CPU escape hatch.
+`plane_commit` takes the kernel on TPU for every call shape: both
+metadata layouts (packed uint32 and the boolean REPRO_NO_PACK=1 planes)
+and the `b_load` shape (`set_dirty=None`, served as an all-False dirty
+mask, which leaves wdirty bitwise unchanged).
 """
 from __future__ import annotations
 
@@ -49,15 +50,17 @@ def plane_commit(wvalid, wdirty, b, o, set_valid, set_dirty, *,
     """Fused metadata-plane front-end: pre-op wvalid/wdirty bit reads +
     per-lane flag OR, one pass over both planes.  Returns
     (wvalid', wdirty', was_valid, was_dirty) — see `ref.plane_commit_ref`.
-    `set_dirty=None` statically skips the wdirty update (`b_load`)."""
+    `set_dirty=None` is the `b_load` shape: the reference skips the
+    wdirty update statically, the kernel ORs an all-False mask — wdirty
+    comes back bitwise unchanged either way."""
     if use_pallas is None:
         use_pallas = common.use_pallas()
-    # the Pallas kernel targets the packed production layout only; the
-    # boolean escape-hatch layout (REPRO_NO_PACK=1) always refs
-    if not use_pallas or wvalid.dtype == jnp.bool_ or set_dirty is None:
+    if not use_pallas:
         return ref.plane_commit_ref(wvalid, wdirty, b, o,
                                     set_valid, set_dirty)
     if interpret is None:
         interpret = common.interpret()
+    if set_dirty is None:
+        set_dirty = jnp.zeros_like(jnp.asarray(set_valid, bool))
     return plane_commit_pallas(wvalid, wdirty, b, o, set_valid, set_dirty,
                                interpret=interpret)

@@ -17,6 +17,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -60,33 +61,39 @@ def selective_flush_pallas(bank: jnp.ndarray, indices: jnp.ndarray,
     )(indices, bank)
 
 
-def _writeback_kernel(idx_ref, l2_ref, row_ref, dirty_ref, out_ref):
-    i = pl.program_id(0)
-    valid = idx_ref[i] >= 0
-    sel = (dirty_ref[...] != 0) & valid
-    # The index list is pre-sorted, so duplicate destinations arrive in
-    # consecutive grid steps and the output block stays resident: merge onto
-    # the previous step's result instead of re-reading the (stale) L2 block.
-    first = (i == 0) | (idx_ref[i] != idx_ref[jnp.maximum(i - 1, 0)])
-    base = jnp.where(first, l2_ref[...], out_ref[...])
-    out_ref[...] = jnp.where(sel, row_ref[...], base)
+def _writeback_kernel(idx_ref, l2_ref, rows_ref, dirty_ref, out_ref, *,
+                      packed):
+    """Sequential masked merge of every list entry into the resident L2
+    bank.  The whole bank, the drained rows and their dirty masks sit in
+    VMEM; entry i's destination block is a dynamic sublane offset read
+    from the SMEM index list.  Walking the list in order IS the
+    reference's last-writer-wins priority, so duplicate destinations
+    need no sort.  Packed masks (uint32 word-bitmask lanes carried as
+    int32) expand in-register: word w is bit w & 31 of lane w >> 5."""
+    nb, w = out_ref.shape
+    out_ref[...] = l2_ref[...]
+    words = lax.broadcasted_iota(jnp.int32, (1, w), 1)
 
+    def body(i, carry):
+        b = idx_ref[0, i]
 
-def _writeback_kernel_packed(idx_ref, l2_ref, row_ref, dirty_ref, out_ref):
-    """`_writeback_kernel` with the dirty mask as packed uint32 word-bitmask
-    lanes (bit pattern carried as int32): the per-word mask is expanded
-    in-register — shift each lane across a 32-wide iota and take bit 0 —
-    so the DMA engine moves ceil(W/32) mask words per block, not W bytes."""
-    i = pl.program_id(0)
-    valid = idx_ref[i] >= 0
-    w = out_ref.shape[-1]
-    words = dirty_ref[...]                               # [1, L] bit lanes
-    shifts = jax.lax.broadcasted_iota(jnp.int32, (1, words.shape[-1], 32), 2)
-    bits = (words[:, :, None] >> shifts) & 1             # arithmetic >> is
-    sel = (bits.reshape(1, -1)[:, :w] != 0) & valid      # bit-exact after &1
-    first = (i == 0) | (idx_ref[i] != idx_ref[jnp.maximum(i - 1, 0)])
-    base = jnp.where(first, l2_ref[...], out_ref[...])
-    out_ref[...] = jnp.where(sel, row_ref[...], base)
+        @pl.when((b >= 0) & (b < nb))
+        def _merge():
+            d = dirty_ref[pl.ds(i, 1), :]
+            if packed:
+                sel = jnp.zeros((1, w), jnp.int32)
+                for lane in range(d.shape[-1]):
+                    bits = (d[:, lane:lane + 1] >> (words & 31)) & 1
+                    sel = jnp.where((words >> 5) == lane, bits, sel)
+            else:
+                sel = d
+            cur = out_ref[pl.ds(b, 1), :]
+            out_ref[pl.ds(b, 1), :] = jnp.where(
+                sel != 0, rows_ref[pl.ds(i, 1), :], cur)
+
+        return carry
+
+    lax.fori_loop(0, idx_ref.shape[1], body, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -97,46 +104,27 @@ def drain_writeback_pallas(l2: jnp.ndarray, rows: jnp.ndarray,
     drain writeback, §2.2/§4.2): out = l2 with rows[i] merged into block
     indices[i] under the per-word dirty mask.
 
-    Scatter twin of `selective_flush_pallas`: the drained-block index list
-    is scalar-prefetched so both the *input* L2 block and the *output* block
-    of each grid step are selected dynamically by the DMA engine, and the L2
-    bank is input/output-aliased so untouched blocks stay in place.  The
-    sequential grid gives deterministic last-writer-wins merging for
-    duplicate indices (same order as the jnp reference).
+    One kernel invocation, no grid: the bank, rows and masks are whole
+    VMEM blocks (full-array blocks satisfy the TPU tiling rule at any
+    W), the index list is an SMEM block, and the merge is an in-kernel
+    loop over the list (`_writeback_kernel`).  The L2 bank is
+    input/output-aliased.  Under `jax.vmap` (the `run_*_many` replica
+    path) the batch becomes a one-axis grid over the same blocks.
 
-    l2 [n_blocks, W]; rows [m, W]; dirty [m, W] bool OR [m, ceil(W/32)]
-    packed uint32 word-bitmask rows (DESIGN.md §8 — expanded in-kernel by
-    `_writeback_kernel_packed`); indices [m] int32 (-1 pad entries write
-    nothing).  Returns the merged [n_blocks, W] bank."""
-    n_blocks, block_size = l2.shape
-    m = indices.shape[0]
+    l2 [n_blocks, W] int32; rows [m, W]; dirty [m, W] bool OR
+    [m, ceil(W/32)] packed uint32 word-bitmask rows (DESIGN.md §8);
+    indices [m] int32 (entries outside [0, n_blocks) write nothing).
+    Returns the merged [n_blocks, W] bank, bitwise
+    `ref.drain_writeback_ref`."""
     packed = dirty.dtype != jnp.bool_
-    safe = jnp.where((indices >= 0) & (indices < n_blocks), indices, -1)
-    # group duplicate destinations into consecutive grid steps; the sort is
-    # stable, so within a destination the original (priority) order survives
-    order = jnp.argsort(safe, stable=True)
-    safe = safe[order]
-    rows = rows[order]
-    dirty = dirty[order]
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(m,),
-        in_specs=[
-            # pad entries (-1) clamp to block 0; the kernel's valid flag
-            # turns the write into a copy of that block onto itself
-            pl.BlockSpec((1, block_size),
-                         lambda i, idx: (jnp.maximum(idx[i], 0), 0)),
-            pl.BlockSpec((1, block_size), lambda i, idx: (i, 0)),
-            pl.BlockSpec((1, dirty.shape[-1]), lambda i, idx: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_size),
-                               lambda i, idx: (jnp.maximum(idx[i], 0), 0)),
-    )
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
     return pl.pallas_call(
-        _writeback_kernel_packed if packed else _writeback_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_blocks, block_size), l2.dtype),
+        functools.partial(_writeback_kernel, packed=packed),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), vmem, vmem, vmem],
+        out_specs=vmem,
+        out_shape=jax.ShapeDtypeStruct(l2.shape, l2.dtype),
         input_output_aliases={1: 0},   # l2 bank updated in place
         interpret=interpret,
-    )(safe, l2, rows, dirty.astype(jnp.int32))
+    )(jnp.asarray(indices, jnp.int32).reshape(1, -1), l2, rows,
+      lax.bitcast_convert_type(dirty, jnp.int32) if packed
+      else dirty.astype(jnp.int32))

@@ -23,19 +23,14 @@ FAIL self-checks on workloads with remote turns (local-scope remote sync
 is the paper's staleness demo) — `check_ok: false` in those rows is the
 workload subsystem working, not a bug.
 
-Also runs two worksteal steady-state A/Bs in subprocesses (the toggles
-are read at import, so a fresh process per arm is the only honest
-measurement):
-
-  * donation_ab — REPRO_NO_DONATE (buffer donation through the jit
-    boundary, the first ROADMAP n_wgs=256 candidate);
-  * pack_ab     — REPRO_NO_PACK (packed uint32 word-bitmask metadata
-    planes vs the boolean layout, DESIGN.md §8 — the fix for the
-    in-loop-scatter bound the donation A/B exonerated).
+The sweep is one process and starts no child: a process that has
+imported JAX holds the chip, so a child that needs it would fail.  The
+toggles read at import (REPRO_NO_DONATE, REPRO_NO_PACK) are measured as
+separate top-level invocations (`env REPRO_NO_PACK=1 python -m
+repro.workloads.sweep ...`).
 
 Schema v3 additions (benchmarks/SCHEMA.md): per-run `table_geometry`
-(LR/PA sets×ways) and top-level `packed_metadata`, plus the `pack_ab`
-section.
+(LR/PA sets×ways) and top-level `packed_metadata`.
 
 Schema v5 additions (elastic alive-set PR, DESIGN.md §10): per-run
 churn columns (`churn_events`, `churn_rate`, `recovered`,
@@ -99,10 +94,8 @@ commutation rule holding in vivo) and reports the wall-clock effect.
 Usage:
   PYTHONPATH=src python -m repro.workloads.sweep \
       [--workloads all] [--scenarios baseline scope_only rsp srsp]
-      [--sizes 16 64] [--seeds 2] [--iters 2] [--no-donation]
-      [--donation-sizes 64 256] [--no-pack-ab] [--pack-sizes 64 256]
-      [--no-remote-batch-ab] [--no-churn] [--fused-scenarios srsp]
-      [--no-fuse-ab] [--fuse-sizes 64 256] [--out BENCH_workloads.json]
+      [--sizes 16 64] [--seeds 2] [--iters 2] [--no-remote-batch-ab]
+      [--no-churn] [--fused-scenarios srsp] [--no-fuse-ab] [--fuse-sizes 64 256] [--out BENCH_workloads.json]
 """
 from __future__ import annotations
 
@@ -110,7 +103,6 @@ import _thread
 import argparse
 import json
 import os
-import subprocess
 import sys
 import tempfile
 import threading
@@ -129,7 +121,7 @@ from repro import workloads
 from repro.core import protocol as P
 from repro.kernels import common as kcommon
 from repro.obs import export as obs_export, metrics, trace as T
-from repro.runtime import fault as rtfault
+from repro.runtime import compile_cache, fault as rtfault
 from repro.traffic.samplers import TrafficConfig
 from repro.workloads import faults, harness
 
@@ -375,77 +367,6 @@ def measure_host_init(mod, name, scenario, n_agents, iters,
     return rec
 
 
-# ---------------- subprocess A/Bs (donation / packed metadata) -------------
-# Both toggles are read once at import of their module, so each arm runs in
-# a fresh subprocess with the env var set — the only honest measurement.
-
-_WS_SNIPPET = r"""
-import json, sys, time
-import numpy as np
-import jax, jax.numpy as jnp
-from repro.core.worksteal import WorkStealSim, WSConfig
-from repro.data.graphs import collab_like
-
-n_wgs, iters = int(sys.argv[1]), int(sys.argv[2])
-n_chunks = max(2 * n_wgs, 64)
-ws = WSConfig(n_wgs=n_wgs, chunk_cap=32, n_chunks_max=n_chunks)
-g = collab_like(n=32 * (n_chunks // 2), m=4, seed=2)
-sim = WorkStealSim(ws, "srsp", "batched")
-store = sim.make_store()
-last_inv = jnp.zeros((ws.n_wgs,), jnp.float32)
-frontier = np.arange(g.n, dtype=np.int32)
-t0 = time.perf_counter()
-store, last_inv, e, _ = sim.run_iteration(store, frontier, g.degrees, last_inv)
-jax.block_until_ready(store.counters.cycles)
-compile_s = time.perf_counter() - t0
-times = []
-for _ in range(max(1, iters)):
-    t0 = time.perf_counter()
-    store, last_inv, e, _ = sim.run_iteration(store, frontier, g.degrees,
-                                              last_inv)
-    jax.block_until_ready(store.counters.cycles)
-    times.append(time.perf_counter() - t0)
-print(json.dumps({"compile_s": round(compile_s, 4),
-                  "steady_s_per_iter": round(float(np.mean(times)), 5),
-                  "proc_errors": int(e)}))
-"""
-
-
-def _measure_ws_subprocess(n_wgs, iters, env_overrides: dict, label: str):
-    """One worksteal srsp steady-state arm in a fresh subprocess."""
-    env = dict(os.environ)
-    src = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", ".."))
-    env["PYTHONPATH"] = src + (os.pathsep + env["PYTHONPATH"]
-                               if env.get("PYTHONPATH") else "")
-    env.update(env_overrides)
-    out = subprocess.run(
-        [sys.executable, "-c", _WS_SNIPPET, str(n_wgs), str(iters)],
-        capture_output=True, text=True, env=env)
-    if out.returncode != 0:
-        print(out.stderr[-2000:], file=sys.stderr)
-        raise RuntimeError(f"{label} subprocess failed: n_wgs={n_wgs} "
-                           f"env={env_overrides}")
-    rec = json.loads(out.stdout.strip().splitlines()[-1])
-    rec.update({"n_wgs": n_wgs, "workload": "worksteal",
-                "scenario": "srsp", "engine": "batched"})
-    return rec
-
-
-def measure_donation(n_wgs, iters, donate: bool):
-    rec = _measure_ws_subprocess(
-        n_wgs, iters, {"REPRO_NO_DONATE": "0" if donate else "1"},
-        "donation")
-    rec["donate"] = donate
-    return rec
-
-
-def measure_pack(n_wgs, iters, packed: bool):
-    rec = _measure_ws_subprocess(
-        n_wgs, iters, {"REPRO_NO_PACK": "0" if packed else "1"}, "pack")
-    rec["packed"] = packed
-    return rec
-
-
 # ---------------- churned robustness cell (schema v5, DESIGN.md §10) -------
 
 def measure_churned_cell(iters):
@@ -657,15 +578,6 @@ def main(argv=None):
     ap.add_argument("--seeds", type=int, default=2,
                     help="replicas per vmapped cell (one compilation)")
     ap.add_argument("--iters", type=int, default=2)
-    ap.add_argument("--no-donation", action="store_true",
-                    help="skip the buffer-donation A/B")
-    ap.add_argument("--donation-sizes", nargs="+", type=int,
-                    default=[64, 256])
-    ap.add_argument("--donation-iters", type=int, default=2)
-    ap.add_argument("--no-pack-ab", action="store_true",
-                    help="skip the packed-vs-boolean metadata A/B")
-    ap.add_argument("--pack-sizes", nargs="+", type=int, default=[64, 256])
-    ap.add_argument("--pack-iters", type=int, default=2)
     ap.add_argument("--no-remote-batch-ab", action="store_true",
                     help="skip the batched-vs-serialized remote-turn A/B")
     ap.add_argument("--remote-batch-sizes", nargs="+", type=int,
@@ -699,6 +611,7 @@ def main(argv=None):
                          "(only written under REPRO_TRACE=1)")
     ap.add_argument("--out", default="BENCH_workloads.json")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     names = workloads.available() if args.workloads == ["all"] \
         else args.workloads
@@ -932,42 +845,6 @@ def main(argv=None):
                         bat["steady_s_per_run"]
                         / fus["steady_s_per_run"], 3)}
 
-    donation = []
-    if not args.no_donation:
-        for n_wgs in args.donation_sizes:
-            for donate in (True, False):
-                rec = measure_donation(n_wgs, args.donation_iters, donate)
-                donation.append(rec)
-                print(f"donation n_wgs={n_wgs} donate={donate}: "
-                      f"steady={rec['steady_s_per_iter']:.3f}s/iter "
-                      f"compile={rec['compile_s']:.1f}s", flush=True)
-        for n_wgs in args.donation_sizes:
-            on = next(r for r in donation
-                      if r["n_wgs"] == n_wgs and r["donate"])
-            off = next(r for r in donation
-                       if r["n_wgs"] == n_wgs and not r["donate"])
-            comparisons[f"donation/n_wgs={n_wgs}"] = {
-                "steady_speedup_donate": round(
-                    off["steady_s_per_iter"] / on["steady_s_per_iter"], 3)}
-
-    pack_ab = []
-    if not args.no_pack_ab:
-        for n_wgs in args.pack_sizes:
-            for packed in (True, False):
-                rec = measure_pack(n_wgs, args.pack_iters, packed)
-                pack_ab.append(rec)
-                print(f"pack n_wgs={n_wgs} packed={packed}: "
-                      f"steady={rec['steady_s_per_iter']:.3f}s/iter "
-                      f"compile={rec['compile_s']:.1f}s", flush=True)
-        for n_wgs in args.pack_sizes:
-            on = next(r for r in pack_ab
-                      if r["n_wgs"] == n_wgs and r["packed"])
-            off = next(r for r in pack_ab
-                       if r["n_wgs"] == n_wgs and not r["packed"])
-            comparisons[f"packed/n_wgs={n_wgs}"] = {
-                "steady_speedup_packed": round(
-                    off["steady_s_per_iter"] / on["steady_s_per_iter"], 3)}
-
     remote_batch_ab = []
     if not args.no_remote_batch_ab:
         for n in args.remote_batch_sizes:
@@ -1127,8 +1004,6 @@ def main(argv=None):
                        "gap_mean": 8.0, "burstiness": 4.0}},
         "runs": runs,
         "serving": serving,
-        "donation_ab": donation,
-        "pack_ab": pack_ab,
         "remote_batch_ab": remote_batch_ab,
         "fuse_ab": fuse_ab,
         "comparisons": comparisons,
