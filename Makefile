@@ -1,7 +1,7 @@
 PYTHON ?= python
 export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH))
 
-.PHONY: test test-slow test-all bench bench-full bench-kernels sweep \
+.PHONY: test test-slow test-all bench bench-full sweep \
 	sweep-smoke trace bench-compare traffic
 
 # Tier-1: fast suite (slow-marked full-size sims excluded via pyproject addopts)
@@ -25,12 +25,6 @@ bench:
 bench-full:
 	$(PYTHON) benchmarks/protocol_engine_bench.py --apps pagerank sssp \
 	  --scenarios baseline steal_only rsp srsp --out BENCH_protocol_engine.json
-
-# Kernel micro-benchmarks (CSV to stdout): per-kernel jnp-reference wall
-# times incl. the fused-turn trip-plan and plane-commit surfaces at
-# n_wgs in {64,256,1024}, packed and boolean metadata layouts
-bench-kernels:
-	$(PYTHON) benchmarks/kernel_bench.py
 
 # Workload-subsystem sweep: protocol x workload x n_agents grid plus the
 # in-process engine A/Bs -> BENCH_workloads.json

@@ -16,15 +16,13 @@ backend change could split one run across modes):
 
 REPRO_KERNEL_MODE (read at import, like REPRO_NO_PACK/REPRO_NO_DONATE)
 overrides the automatic choice with any of the three names.  Benchmarks
-call `note_benchmark()` before timing and record `kernel_mode()` in
-their JSON, so an interpret-mode number can never masquerade as a real
-measurement (warned loudly, and visible in the artifact).
+record `kernel_mode()` in their JSON, so an interpret-mode number can
+never masquerade as a real measurement.
 """
 from __future__ import annotations
 
 import functools
 import os
-import warnings
 
 import jax
 
@@ -60,20 +58,6 @@ def default_interpret() -> bool:
     equivalence tests): interpret everywhere except a real TPU.  Kept
     for back-compat; now derived from the cached process-wide mode."""
     return kernel_mode() != "pallas"
-
-
-def note_benchmark(what: str) -> str:
-    """Benchmark entry hook: returns `kernel_mode()` for the bench JSON
-    and warns loudly if the process would time interpret-mode kernels —
-    a number from the Python interpreter loop is not a measurement."""
-    mode = kernel_mode()
-    if mode == "interpret":
-        warnings.warn(
-            f"{what}: benchmarking with kernel_mode='interpret' "
-            f"(REPRO_KERNEL_MODE) — interpret-mode Pallas timings are "
-            f"not meaningful; unset REPRO_KERNEL_MODE or use the jnp "
-            f"reference path", stacklevel=2)
-    return mode
 
 
 def cdiv(a: int, b: int) -> int:

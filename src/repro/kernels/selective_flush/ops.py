@@ -55,8 +55,8 @@ def drain_writeback(l2: jnp.ndarray, rows: jnp.ndarray, dirty: jnp.ndarray,
     (`kernels.common.kernel_mode()`), never re-derived mid-run."""
     if use_pallas is None:
         use_pallas = common.use_pallas()
-    # profiler annotation: the drain scatter is the megakernel-fusion
-    # candidate (ROADMAP) — make it findable in jax.profiler traces
+    # the benchmark's `breakdown` labels read this scope from the
+    # compiled program's op names
     with jax.named_scope("kernels.drain_writeback"):
         if not use_pallas:
             return ref.drain_writeback_ref(l2, rows, dirty, indices)

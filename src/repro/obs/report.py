@@ -22,7 +22,7 @@ def _run_demo(args):
 
     from repro import workloads
     from repro.core import protocol as P
-    from repro.obs import export, trace as T
+    from repro.obs import export, spans, trace as T
     from repro.workloads import faults, harness
 
     victim, at, evt = 0, 5.0, 400.0   # tests/test_churn.py's pinned geometry
@@ -39,7 +39,7 @@ def _run_demo(args):
                       **kw)
     eb = harness.make_elastic(bench, events=events)
     state = T.with_trace(eb.state, args.cap)
-    with jax.profiler.TraceAnnotation(
+    with spans.span(
             f"demo:{args.workload}/{args.scenario}/n={args.n_agents}"):
         fin = harness.run_batched_elastic(eb.wl, state, *eb.ops)
         jax.block_until_ready(fin.s.store.counters.cycles)

@@ -13,16 +13,22 @@ updated with masked scatters inside the jitted schedulers:
 * the elastic engines append churn (leave/crash/join) and recovery
   events; the engines bucket each agent's per-turn charged cycles.
 
+Beside the ring, `trips` counts the engine's `while_loop` trips.  It is
+kept in every log, the disabled one too: it is a property of the engine
+(serial, batched and fused run the same simulation in different numbers
+of trips), so `strip` zeroes it with the rest of the trace.
+
 Ring overflow policy: `head` is a monotonic event count and an event's
 slot is `(position % cap)`, so the buffer always holds the NEWEST `cap`
 events; the oldest are overwritten and `dropped = max(head - cap, 0)`
 is reported by the decoder — overflow loses history, never corrupts.
 
 Enablement is carried by SHAPE, not by a runtime flag: a disabled log
-has zero-capacity columns and every record_* helper returns its input
-unchanged via a trace-time Python conditional — the disabled path is
-*provably* absent from the compiled program, so every bitwise
-equivalence suite holds trivially with tracing off.  `REPRO_TRACE=1`
+has zero-capacity columns and every record_* helper but `record_trip`
+returns its input unchanged via a trace-time Python conditional — the
+disabled path is *provably* absent from the compiled program, and the
+trip counter, which is, is stripped with the trace by every bitwise
+equivalence suite.  `REPRO_TRACE=1`
 (read once at import, mirroring REPRO_NO_PACK) makes `make_store`
 allocate `REPRO_TRACE_CAP` (default 4096) slots; `with_trace` enables
 tracing on an existing state in-process (tests, the report demo).
@@ -62,6 +68,7 @@ class TraceLog(NamedTuple):
     cap == 0 (the `clock` extent) IS the disabled state; the histogram
     agent axis collapses to 0 with it so a disabled log is empty."""
     head: jnp.ndarray       # [] i32 monotonic event count
+    trips: jnp.ndarray      # [] i32 engine while-loop trips (always kept)
     clock: jnp.ndarray      # [cap] f32 issuing lane's cycles at issue
     agent: jnp.ndarray      # [cap] i32
     kind: jnp.ndarray       # [cap] i32 ACQUIRE..RECOVER
@@ -78,6 +85,7 @@ def make(cap: int, n_agents: int) -> TraceLog:
     m = n_agents if cap else 0
     return TraceLog(
         head=jnp.zeros((), jnp.int32),
+        trips=jnp.zeros((), jnp.int32),
         clock=jnp.zeros((cap,), jnp.float32),
         agent=jnp.full((cap,), -1, jnp.int32),
         kind=jnp.full((cap,), -1, jnp.int32),
@@ -116,13 +124,24 @@ def with_trace(state, cap: int = None):
 
 def strip(state):
     """Replace the trace with the disabled log — for bitwise comparisons
-    across paths whose event ORDER legitimately differs (serial vs
-    batched issue the same ops at the same costs in different calls)."""
+    across paths whose event ORDER legitimately differs (serial and
+    batched run the same ops at the same costs in different calls), or
+    whose trip counts do."""
     return with_trace(state, 0)
 
 
+def trip_count(state):
+    """The `trips` leaf of a Store / workload / elastic state."""
+    if hasattr(state, "trace") and hasattr(state, "counters"):  # Store
+        return state.trace.trips
+    if hasattr(state, "store"):
+        return trip_count(state.store)
+    return trip_count(state.s)                          # ElasticState
+
+
 # --------------------------------------------------------------------------
-# jit-side recording (every helper is a Python-level identity when disabled)
+# jit-side recording (every helper but record_trip is a Python-level
+# identity when disabled)
 # --------------------------------------------------------------------------
 
 def _append(tl: TraceLog, active, *, clock, agent, kind, scope, addr,
@@ -185,6 +204,12 @@ def record_event(st, mask, kind, outcome, *, addr=None, clock=None,
                  scope=0, addr=-1 if addr is None else addr,
                  cycles=cycles, outcome=outcome)
     return st._replace(trace=tl)
+
+
+def record_trip(st):
+    """Count one engine trip.  Runs whether tracing is on or off."""
+    tl = st.trace
+    return st._replace(trace=tl._replace(trips=tl.trips + 1))
 
 
 def record_turn(st, clock0):

@@ -79,9 +79,16 @@ from jax import lax
 
 from repro.core import protocol as P
 from repro.kernels import fused_turn
+from repro.obs import spans
 from repro.obs import trace as T
 
 BIG = jnp.float32(3e38)
+
+# Op-name scopes of every engine (metadata only; the benchmark's trace
+# readers find them in the compiled program): TRIP_SCOPE holds a
+# while-loop trip's condition and body, PLAN_SCOPE the trip plan, the
+# ops that decide who acts this trip and in which batch.
+TRIP_SCOPE, PLAN_SCOPE = "harness.trip", "harness.plan"
 
 # scenario -> protocol name, subsystem-wide (the paper's §5 mapping;
 # worksteal additionally flags which scenarios steal).  A registry: an
@@ -199,14 +206,27 @@ def charge(st: P.Store, mask, cycles) -> P.Store:
 
 
 def _note_turn(s0, s1):
-    """Bucket each agent's charged cycles across one scheduler turn/trip
-    into the trace's per-turn latency histogram (DESIGN.md §11).  A
-    Python-level identity when tracing is off, so the plain engines'
-    bitwise contracts are untouched by default."""
-    if not T.enabled(s1.store.trace):
-        return s1
-    return s1._replace(store=T.record_turn(s1.store,
-                                           s0.store.counters.cycles))
+    """Close one scheduler trip: count it in the trace's `trips` (always,
+    traced or not) and, when tracing is on, bucket each agent's charged
+    cycles across the trip into the per-turn latency histogram
+    (DESIGN.md §11).  The count is engine-dependent; the bitwise
+    contracts compare through `T.strip`, which zeroes it."""
+    st = T.record_trip(s1.store)
+    if T.enabled(st.trace):
+        st = T.record_turn(st, s0.store.counters.cycles)
+    return s1._replace(store=st)
+
+
+def _while(cond, body, state):
+    """`lax.while_loop` with the condition and body under TRIP_SCOPE."""
+
+    def scoped(fn):
+        def run(s):
+            with jax.named_scope(TRIP_SCOPE):
+                return fn(s)
+        return run
+
+    return lax.while_loop(scoped(cond), scoped(body), state)
 
 
 def _serial_turn(wl: Workload, s, wg, can_l, ops):
@@ -227,14 +247,15 @@ def run_serial(wl: Workload, state, *ops):
         return wl.live(wl, s, *ops)
 
     def body(s):
-        can_l = wl.can_local(wl, s, *ops)
-        can_r = wl.can_remote(wl, s, *ops)
-        cand = can_l | can_r
-        clocks = jnp.where(cand, s.store.counters.cycles, BIG)
-        wg = jnp.argmin(clocks).astype(jnp.int32)
+        with jax.named_scope(PLAN_SCOPE):
+            can_l = wl.can_local(wl, s, *ops)
+            can_r = wl.can_remote(wl, s, *ops)
+            cand = can_l | can_r
+            clocks = jnp.where(cand, s.store.counters.cycles, BIG)
+            wg = jnp.argmin(clocks).astype(jnp.int32)
         return _note_turn(s, _serial_turn(wl, s, wg, can_l, ops))
 
-    return lax.while_loop(cond, body, state)
+    return _while(cond, body, state)
 
 
 def _batched_trip(wl: Workload, s, can_l, can_r, horizon, ops):
@@ -253,21 +274,23 @@ def _batched_trip(wl: Workload, s, can_l, can_r, horizon, ops):
     if not wl.has_remote:
         # nothing ever conflicts: every ready agent acts each trip
         if horizon is not None:
-            can_l = can_l & (clocks_all < horizon)
+            with jax.named_scope(PLAN_SCOPE):
+                can_l = can_l & (clocks_all < horizon)
         return wl.local_turn(wl, s, can_l, *ops)
-    cand = can_l | can_r
-    clocks = jnp.where(cand, clocks_all, BIG)
-    wg_min = jnp.argmin(clocks).astype(jnp.int32)
-    sclk = jnp.where(can_r, clocks_all, BIG)
-    ms = jnp.min(sclk)
-    js = jnp.argmin(sclk).astype(jnp.int32)
-    fence = jnp.min(jnp.where(can_l,
-                              clocks_all + wl.remote_bound(wl, s, *ops),
-                              BIG))
-    lex = (clocks_all < ms) | ((clocks_all == ms) & (wgs < js))
-    batch = can_l & lex & (clocks_all <= fence)
-    if horizon is not None:
-        batch = batch & (clocks_all < horizon)
+    with jax.named_scope(PLAN_SCOPE):
+        cand = can_l | can_r
+        clocks = jnp.where(cand, clocks_all, BIG)
+        wg_min = jnp.argmin(clocks).astype(jnp.int32)
+        sclk = jnp.where(can_r, clocks_all, BIG)
+        ms = jnp.min(sclk)
+        js = jnp.argmin(sclk).astype(jnp.int32)
+        fence = jnp.min(jnp.where(can_l,
+                                  clocks_all + wl.remote_bound(wl, s, *ops),
+                                  BIG))
+        lex = (clocks_all < ms) | ((clocks_all == ms) & (wgs < js))
+        batch = can_l & lex & (clocks_all <= fence)
+        if horizon is not None:
+            batch = batch & (clocks_all < horizon)
 
     def do_batch(st):
         return wl.local_turn(wl, st, batch, *ops)
@@ -277,24 +300,25 @@ def _batched_trip(wl: Workload, s, can_l, can_r, horizon, ops):
 
     if remote_cap:
         def do_remote_or_serial(st):
-            # remote candidates preceding every local candidate's
-            # clock (same lex pattern as the local batch, mirrored)
-            lclk = jnp.where(can_l, clocks_all, BIG)
-            ml = jnp.min(lclk)
-            jl = jnp.argmin(lclk).astype(jnp.int32)
-            lexr = (clocks_all < ml) | ((clocks_all == ml) & (wgs < jl))
-            r0 = can_r & lexr
-            if horizon is not None:
-                r0 = r0 & (clocks_all < horizon)
-            raddr = wl.remote_addr(wl, st, *ops)
-            # address dedup: drop a lane iff an earlier (clock, idx)
-            # candidate targets the same address
-            collide = r0[:, None] & r0[None, :] \
-                & (raddr[:, None] == raddr[None, :])
-            earlier = (clocks_all[None, :] < clocks_all[:, None]) \
-                | ((clocks_all[None, :] == clocks_all[:, None])
-                   & (wgs[None, :] < wgs[:, None]))
-            rbatch = r0 & ~jnp.any(collide & earlier, axis=1)
+            with jax.named_scope(PLAN_SCOPE):
+                # remote candidates preceding every local candidate's
+                # clock (same lex pattern as the local batch, mirrored)
+                lclk = jnp.where(can_l, clocks_all, BIG)
+                ml = jnp.min(lclk)
+                jl = jnp.argmin(lclk).astype(jnp.int32)
+                lexr = (clocks_all < ml) | ((clocks_all == ml) & (wgs < jl))
+                r0 = can_r & lexr
+                if horizon is not None:
+                    r0 = r0 & (clocks_all < horizon)
+                raddr = wl.remote_addr(wl, st, *ops)
+                # address dedup: drop a lane iff an earlier (clock, idx)
+                # candidate targets the same address
+                collide = r0[:, None] & r0[None, :] \
+                    & (raddr[:, None] == raddr[None, :])
+                earlier = (clocks_all[None, :] < clocks_all[:, None]) \
+                    | ((clocks_all[None, :] == clocks_all[:, None])
+                       & (wgs[None, :] < wgs[:, None]))
+                rbatch = r0 & ~jnp.any(collide & earlier, axis=1)
             return lax.cond(
                 jnp.any(rbatch),
                 lambda s2: wl.remote_turn_b(wl, s2, rbatch, *ops),
@@ -330,11 +354,12 @@ def run_batched(wl: Workload, state, *ops):
         return wl.live(wl, s, *ops)
 
     def body(s):
-        can_l = wl.can_local(wl, s, *ops)
-        can_r = wl.can_remote(wl, s, *ops) if wl.has_remote else None
+        with jax.named_scope(PLAN_SCOPE):
+            can_l = wl.can_local(wl, s, *ops)
+            can_r = wl.can_remote(wl, s, *ops) if wl.has_remote else None
         return _note_turn(s, _batched_trip(wl, s, can_l, can_r, None, ops))
 
-    return lax.while_loop(cond, body, state)
+    return _while(cond, body, state)
 
 
 @partial(jax.jit, static_argnums=(0,), **_don)
@@ -375,11 +400,12 @@ def _fused_trip(wl: Workload, s, can_l, can_r, horizon, ops):
     remote_cap = (wl.remote_turn_b is not None
                   and wl.remote_addr is not None
                   and wl.proto.remote_batchable)
-    raddr = wl.remote_addr(wl, s, *ops) if remote_cap else None
-    plan = fused_turn.trip_plan(
-        s.store.counters.cycles, can_l, can_r,
-        wl.remote_bound(wl, s, *ops), raddr, horizon,
-        remote_cap=remote_cap)
+    with jax.named_scope(PLAN_SCOPE):
+        raddr = wl.remote_addr(wl, s, *ops) if remote_cap else None
+        plan = fused_turn.trip_plan(
+            s.store.counters.cycles, can_l, can_r,
+            wl.remote_bound(wl, s, *ops), raddr, horizon,
+            remote_cap=remote_cap)
 
     def do_local(st):
         return wl.local_turn(wl, st, plan.lmask, *ops)
@@ -410,11 +436,12 @@ def run_fused(wl: Workload, state, *ops):
         return wl.live(wl, s, *ops)
 
     def body(s):
-        can_l = wl.can_local(wl, s, *ops)
-        can_r = wl.can_remote(wl, s, *ops) if wl.has_remote else None
+        with jax.named_scope(PLAN_SCOPE):
+            can_l = wl.can_local(wl, s, *ops)
+            can_r = wl.can_remote(wl, s, *ops) if wl.has_remote else None
         return _note_turn(s, trip(wl, s, can_l, can_r, None, ops))
 
-    return lax.while_loop(cond, body, state)
+    return _while(cond, body, state)
 
 
 @partial(jax.jit, static_argnums=(0,), **_don)
@@ -573,6 +600,12 @@ def _fire_events(wl: Workload, sched: ChurnSchedule, es: ElasticState,
     return ElasticState(s, alive, recover_at, fired)
 
 
+def _note_fire(es: ElasticState) -> ElasticState:
+    """Close a trip that fired churn events: it counts as a trip and, as
+    no agent took a turn, buckets nothing."""
+    return es._replace(s=es.s._replace(store=T.record_trip(es.s.store)))
+
+
 def _elastic_cond(wl: Workload, sched: ChurnSchedule, es: ElasticState,
                   ops):
     """Loop guard: work remains AND progress is possible — a live agent
@@ -596,20 +629,22 @@ def run_serial_elastic(wl: Workload, es: ElasticState,
         return _elastic_cond(wl, sched, e, ops)
 
     def body(e):
-        can_l, can_r = _elastic_ready(wl, e, ops)
-        clocks = jnp.where(can_l | can_r, e.s.store.counters.cycles, BIG)
-        mcc = jnp.min(clocks)
-        wg = jnp.argmin(clocks).astype(jnp.int32)
-        ec = _event_horizon(sched, e)
+        with jax.named_scope(PLAN_SCOPE):
+            can_l, can_r = _elastic_ready(wl, e, ops)
+            clocks = jnp.where(can_l | can_r, e.s.store.counters.cycles,
+                               BIG)
+            mcc = jnp.min(clocks)
+            wg = jnp.argmin(clocks).astype(jnp.int32)
+            ec = _event_horizon(sched, e)
         return lax.cond(
             (ec <= mcc) & (ec < BIG),
-            lambda e2: _fire_events(wl, sched, e2, mcc, ops),
+            lambda e2: _note_fire(_fire_events(wl, sched, e2, mcc, ops)),
             lambda e2: e2._replace(
                 s=_note_turn(e2.s, _serial_turn(wl, e2.s, wg, can_l,
                                                 ops))),
             e)
 
-    return lax.while_loop(cond, body, es)
+    return _while(cond, body, es)
 
 
 @partial(jax.jit, static_argnums=(0,), **_don)
@@ -625,20 +660,22 @@ def run_batched_elastic(wl: Workload, es: ElasticState,
         return _elastic_cond(wl, sched, e, ops)
 
     def body(e):
-        can_l, can_r = _elastic_ready(wl, e, ops)
-        clocks = jnp.where(can_l | can_r, e.s.store.counters.cycles, BIG)
-        mcc = jnp.min(clocks)
-        ec = _event_horizon(sched, e)
+        with jax.named_scope(PLAN_SCOPE):
+            can_l, can_r = _elastic_ready(wl, e, ops)
+            clocks = jnp.where(can_l | can_r, e.s.store.counters.cycles,
+                               BIG)
+            mcc = jnp.min(clocks)
+            ec = _event_horizon(sched, e)
         cr = can_r if wl.has_remote else None
         return lax.cond(
             (ec <= mcc) & (ec < BIG),
-            lambda e2: _fire_events(wl, sched, e2, mcc, ops),
+            lambda e2: _note_fire(_fire_events(wl, sched, e2, mcc, ops)),
             lambda e2: e2._replace(
                 s=_note_turn(e2.s, _batched_trip(wl, e2.s, can_l, cr, ec,
                                                  ops))),
             e)
 
-    return lax.while_loop(cond, body, es)
+    return _while(cond, body, es)
 
 
 # Engine registry: unknown names raise with the registered list.
@@ -668,14 +705,36 @@ ENGINES_MANY["batched"] = run_batched_many
 ENGINES_MANY["fused"] = run_fused_many
 
 
-def runner(engine: str):
+class Runner:
+    """A registered engine as `runner`/`runner_many` hand it out: calls
+    the same jitted program, inside an `engine.call` span that carries a
+    call sequence number, and records the output's trip count (one per
+    replica for a vmapped engine) in `obs.spans` under that number.  The
+    count is recorded unfetched, its copy to the host started, so a call
+    never waits for the device.  `lower` is the program's own."""
+
+    def __init__(self, fn: Callable):
+        self.fn = fn
+        self.lower = fn.lower
+
+    def __call__(self, wl: Workload, state, *ops):
+        seq = spans.next_seq()
+        with spans.span("engine.call", seq=seq):
+            out = self.fn(wl, state, *ops)
+        trips = T.trip_count(out)
+        trips.copy_to_host_async()
+        spans.record("engine.trips", seq, trips)
+        return out
+
+
+def runner(engine: str) -> Runner:
     """Registered scheduler by name; unknown names raise with the list."""
-    return ENGINES[engine]
+    return Runner(ENGINES[engine])
 
 
-def runner_many(engine: str):
+def runner_many(engine: str) -> Runner:
     """Vmapped scheduler twin by engine name (sweep replica packing)."""
-    return ENGINES_MANY[engine]
+    return Runner(ENGINES_MANY[engine])
 
 
 def drain_all(cfg: P.ProtoConfig, st: P.Store) -> P.Store:

@@ -120,7 +120,7 @@ import jax.numpy as jnp
 from repro import workloads
 from repro.core import protocol as P
 from repro.kernels import common as kcommon
-from repro.obs import export as obs_export, metrics, trace as T
+from repro.obs import export as obs_export, metrics, spans, trace as T
 from repro.runtime import compile_cache, fault as rtfault
 from repro.traffic.samplers import TrafficConfig
 from repro.workloads import faults, harness
@@ -641,7 +641,7 @@ def main(argv=None):
                     label = f"{name}/{scen}/n={n_agents}/{engine}"
                     t0 = time.perf_counter()
                     wd.start(label)
-                    with jax.profiler.TraceAnnotation(f"cell:{label}"):
+                    with spans.span(f"cell:{label}"):
                         if mod.VMAPPABLE:
                             rec = measure_vmapped(mod, name, scen, n_agents,
                                                   args.seeds, args.iters,
@@ -664,7 +664,7 @@ def main(argv=None):
     if not args.no_churn:
         label = "worksteal/srsp+crash/churned"
         wd.start(label)
-        with jax.profiler.TraceAnnotation(f"cell:{label}"):
+        with spans.span(f"cell:{label}"):
             rec = measure_churned_cell(args.iters)
         wd.stop()
         harvest(rec, label)
@@ -678,7 +678,7 @@ def main(argv=None):
     if not args.no_churn and "kv_serving" in names:
         label = "kv_serving/srsp+crash/churned"
         wd.start(label)
-        with jax.profiler.TraceAnnotation(f"cell:{label}"):
+        with spans.span(f"cell:{label}"):
             rec = measure_churned_serving(args.iters)
         wd.stop()
         harvest(rec, label)
@@ -707,7 +707,7 @@ def main(argv=None):
                              f"/n={n}/{engine}")
                     t0 = time.perf_counter()
                     wd.start(label)
-                    with jax.profiler.TraceAnnotation(f"cell:{label}"):
+                    with spans.span(f"cell:{label}"):
                         rec = measure_vmapped(
                             kv_mod, "kv_serving", scen, n,
                             args.serving_seeds, args.iters, engine,
@@ -752,7 +752,7 @@ def main(argv=None):
                 label = f"serving-scale/kv_serving/{scen}/n={n}/fused"
                 t0 = time.perf_counter()
                 wd.start(label)
-                with jax.profiler.TraceAnnotation(f"cell:{label}"):
+                with spans.span(f"cell:{label}"):
                     rec = measure_vmapped(
                         kv_mod, "kv_serving", scen, n,
                         args.serving_scale_replicas, 1, "fused",

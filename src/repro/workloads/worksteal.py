@@ -45,6 +45,7 @@ from repro.core import ops as O
 from repro.core import protocol as P
 from repro.core import costmodel, sfifo, tables
 from repro.data.graphs import CSRGraph, collab_like
+from repro.obs import spans
 from repro.workloads import harness
 
 QMETA = 16  # words reserved at the head of each queue (lock/head/tail block)
@@ -638,24 +639,31 @@ def build(scenario: str, n_agents: int, seed: int = 0, *,
     kw.setdefault("chunk_cap", 8)
     kw.setdefault("n_chunks_max", max(2 * n_agents, 8))
     ws = WSConfig(n_wgs=n_agents, **kw)
-    g = collab_like(n=ws.n_chunks_max * ws.chunk_cap // 2, m=3,
-                    seed=1 + seed)
+    with spans.span("inputs.graph"):
+        g = collab_like(n=ws.n_chunks_max * ws.chunk_cap // 2, m=3,
+                        seed=1 + seed)
     wl = build_workload(ws, p, steal)
 
     frontier = np.arange(g.n, dtype=np.int32)
-    # skewed ownership: agent 0 owns half the chunks, the rest spread
-    # round-robin — guarantees the imbalance that makes steals happen
-    plan = _chunk_plan(ws, frontier, g.degrees,
-                       lambda c, sel, nc: 0 if c < nc // 2 else c % ws.n_wgs)
-    store = _enqueue_jit(ws, p.acquire_loc_b, p.release_loc_b,
-                         P.make_store(ws.proto_cfg()),
-                         jnp.asarray(plan.owner), jnp.asarray(plan.slot),
-                         jnp.asarray(plan.valid), jnp.asarray(plan.n_enq))
-    state = SimState(store=store, qsize=jnp.asarray(plan.n_enq),
-                     processed=jnp.zeros(ws.n_chunks_max, jnp.int32),
-                     last_inv=jnp.zeros((ws.n_wgs,), jnp.float32),
-                     rounds=jnp.int32(0), rem=jnp.asarray(plan.rem))
-    ops = (jnp.asarray(plan.count), jnp.asarray(plan.edges))
+    with spans.span("inputs.plan"):
+        # skewed ownership: agent 0 owns half the chunks, the rest spread
+        # round-robin — guarantees the imbalance that makes steals happen
+        plan = _chunk_plan(ws, frontier, g.degrees,
+                           lambda c, sel, nc: (0 if c < nc // 2
+                                               else c % ws.n_wgs))
+    with spans.span("inputs.store"):
+        store = P.make_store(ws.proto_cfg())
+        owner, slot, valid, n_enq, rem, count, edges = map(
+            jnp.asarray, (plan.owner, plan.slot, plan.valid, plan.n_enq,
+                          plan.rem, plan.count, plan.edges))
+        processed = jnp.zeros(ws.n_chunks_max, jnp.int32)
+        last_inv = jnp.zeros((ws.n_wgs,), jnp.float32)
+    with spans.span("inputs.enqueue"):
+        store = _enqueue_jit(ws, p.acquire_loc_b, p.release_loc_b, store,
+                             owner, slot, valid, n_enq)
+    state = SimState(store=store, qsize=n_enq, processed=processed,
+                     last_inv=last_inv, rounds=jnp.int32(0), rem=rem)
+    ops = (count, edges)
 
     def check(final: SimState) -> dict:
         proc = np.asarray(final.processed)

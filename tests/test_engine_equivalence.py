@@ -106,7 +106,8 @@ def test_trace_on_preserves_results(name):
     b2 = workloads.get(name).build("srsp", 4, seed=3)
     on = harness.run_batched(b2.wl, T.with_trace(b2.state, 512), *b2.ops)
     assert int(on.store.trace.head) > 0, name      # tracing really ran
-    for la, lb in zip(jax.tree.leaves(off), jax.tree.leaves(T.strip(on))):
+    for la, lb in zip(jax.tree.leaves(T.strip(off)),
+                      jax.tree.leaves(T.strip(on))):
         np.testing.assert_array_equal(np.asarray(la), np.asarray(lb),
                                       err_msg=name)
     assert b2.check(on)["ok"], name
@@ -192,7 +193,8 @@ def test_fused_engine_trace_on_preserves_results():
     b2 = workloads.get("producer_consumer").build("srsp", 4, seed=3)
     on = harness.run_fused(b2.wl, T.with_trace(b2.state, 512), *b2.ops)
     assert int(on.store.trace.head) > 0
-    for la, lb in zip(jax.tree.leaves(off), jax.tree.leaves(T.strip(on))):
+    for la, lb in zip(jax.tree.leaves(T.strip(off)),
+                      jax.tree.leaves(T.strip(on))):
         np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
     jax.clear_caches()
 
