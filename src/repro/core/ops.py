@@ -237,7 +237,7 @@ def release(proto: P.Protocol, cfg: P.ProtoConfig, st: P.Store, active,
 def _l1_state(cfg, st, addrs, plane):
     """Pre-op L1 metadata bit per lane at `addrs` (trace classification)."""
     b, o = P._split(cfg, _bcast(addrs, cfg.n_caches))
-    return P._pl_get(plane, jnp.arange(cfg.n_caches), b, o)
+    return P._pl_get(cfg, plane, jnp.arange(cfg.n_caches), b, o)
 
 
 def load(cfg: P.ProtoConfig, st: P.Store, active, addrs, scope=LOCAL):

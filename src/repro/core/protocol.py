@@ -9,8 +9,8 @@ ops instead of per-word dynamic slices:
 
     Store.l2      [n_blocks, block_words]            word values at L2
     Store.l1      [n_caches, n_blocks, block_words]  per-cache cached values
-    Store.wvalid  [n_caches, n_blocks, ceil(W/32)]   local copy is readable
-    Store.wdirty  [n_caches, n_blocks, ceil(W/32)]   local copy not written back
+    Store.wvalid  [n_caches, n_blocks * L]           local copy is readable
+    Store.wdirty  [n_caches, n_blocks * L]           local copy not written back
     Store.fifo    batched SFifo        dirty-block FIFO  (QuickRelease)
     Store.lr      batched LRTbl        sRSP local-release table (set-assoc)
     Store.pa      batched PATbl        sRSP promoted-acquire table (set-assoc)
@@ -22,10 +22,13 @@ word-bitmasks** (`core/bitmask.py`, DESIGN.md §8): bit `o % 32` of lane
 `o // 32` tracks block offset `o`, so the planes carry 1 bit per word
 instead of the boolean layout's byte — the in-loop scatters that bound the
 batched engine at n_wgs=256 shrink with them.  `REPRO_NO_PACK=1` (read
-once at import, mirroring REPRO_NO_DONATE) falls back to the boolean
-`[n_caches, n_blocks, W]` layout; the sweep A/B-tests the two in
-subprocesses.  All plane access goes through the `_pl_*`/`_rows_*`
-helpers below, which are the only layout-aware code.
+once at import, mirroring REPRO_NO_DONATE) falls back to boolean flags,
+one lane per word (L = W).  Either way a plane is stored lane-dense,
+`[n_caches, n_blocks * L]` with L = `ProtoConfig.meta_lanes`: lane `w` of
+block `b` is column `b * L + w`, and no axis of extent 1 is left for a
+TPU layout to pad to 128 (DESIGN.md §8).  All plane access goes through
+the `_pl_*`/`_rows_*` helpers below, which are the only layout-aware
+code.
 
 All operations are pure `(store, ...) -> (store', ...)` functions and fully
 jittable; the cost model charges cycles/L2-transactions as a side channel in
@@ -105,7 +108,8 @@ class ProtoConfig:
 
     @property
     def meta_lanes(self) -> int:
-        """Last-axis extent of the wvalid/wdirty planes in this layout."""
+        """Plane columns per block in this layout (L): ceil(W/32) packed
+        words, or W boolean flags."""
         return bitmask.n_lanes(self.block_words) if PACKED \
             else self.block_words
 
@@ -147,8 +151,8 @@ def lease_clear(st: "Store", active) -> "Store":
 class Store(NamedTuple):
     l2: jnp.ndarray        # [n_blocks, W]
     l1: jnp.ndarray        # [n_caches, n_blocks, W]
-    wvalid: jnp.ndarray    # [n_caches, n_blocks, meta_lanes] (see PACKED)
-    wdirty: jnp.ndarray    # [n_caches, n_blocks, meta_lanes]
+    wvalid: jnp.ndarray    # [n_caches, n_blocks * meta_lanes] (see PACKED)
+    wdirty: jnp.ndarray    # [n_caches, n_blocks * meta_lanes]
     fifo: sfifo.SFifo      # leaves have leading [n_caches]
     lr: tables.LRTbl
     pa: tables.PATbl
@@ -159,7 +163,7 @@ class Store(NamedTuple):
 
 def make_store(cfg: ProtoConfig) -> Store:
     n, nb, w = cfg.n_caches, cfg.n_blocks, cfg.block_words
-    plane = jnp.zeros((n, nb, cfg.meta_lanes),
+    plane = jnp.zeros((n, nb * cfg.meta_lanes),
                       jnp.uint32 if PACKED else jnp.bool_)
     stack = lambda t: jax.tree.map(lambda x: jnp.broadcast_to(x, (n,) + x.shape).copy(), t)
     return Store(
@@ -222,32 +226,47 @@ def _fill(cfg: ProtoConfig, val):
 # metadata-plane access — the ONLY layout-aware code (packed vs boolean)
 # --------------------------------------------------------------------------
 
-def _pl_get(plane, lane, b, o):
+def _pl_col(cfg: ProtoConfig, b, o):
+    """Plane column holding word offset `o` of block `b`: b * L + the
+    offset's lane (its packed word, or the offset itself unpacked)."""
+    w = bitmask.word_index(o) if PACKED else jnp.asarray(o, jnp.int32)
+    return jnp.asarray(b, jnp.int32) * cfg.meta_lanes + w
+
+
+def _pl_get(cfg: ProtoConfig, plane, lane, b, o):
     """Per-lane flag read: flags[lane, b, o] -> bool [n]."""
+    words = plane[lane, _pl_col(cfg, b, o)]
+    return bitmask.test_word(words, o) if PACKED else words
+
+
+def _pl_clear(cfg: ProtoConfig, plane, lane, b, o, off):
+    """Per-lane flag clear: flags[lane, b, o] &= ~off ((lane, b) pairs
+    are distinct, so the scatter is safe)."""
+    c = _pl_col(cfg, b, o)
     if PACKED:
-        return bitmask.test_word(plane[lane, b, bitmask.word_index(o)], o)
-    return plane[lane, b, o]
-
-
-def _pl_set(plane, lane, b, o, on):
-    """Per-lane flag OR: flags[lane, b, o] |= on (lanes with on=False keep
-    their value; (lane, b) pairs are distinct, so the scatter is safe)."""
-    if PACKED:
-        w = bitmask.word_index(o)
-        mask = jnp.where(jnp.asarray(on, bool), bitmask.word_bit(o),
-                         jnp.uint32(0))
-        return plane.at[lane, b, w].set(plane[lane, b, w] | mask)
-    return plane.at[lane, b, o].set(plane[lane, b, o] | on)
-
-
-def _pl_clear(plane, lane, b, o, off):
-    """Per-lane flag clear: flags[lane, b, o] &= ~off."""
-    if PACKED:
-        w = bitmask.word_index(o)
         mask = jnp.where(jnp.asarray(off, bool), bitmask.word_bit(o),
                          jnp.uint32(0))
-        return plane.at[lane, b, w].set(plane[lane, b, w] & ~mask)
-    return plane.at[lane, b, o].set(plane[lane, b, o] & ~off)
+        return plane.at[lane, c].set(plane[lane, c] & ~mask)
+    return plane.at[lane, c].set(plane[lane, c] & ~off)
+
+
+def _row_cols(cfg: ProtoConfig, blks):
+    """Plane columns of whole blocks: [..., L] for blks [...]; a block
+    >= n_blocks maps past the plane, so a scatter there drops."""
+    return (jnp.asarray(blks, jnp.int32)[..., None] * cfg.meta_lanes
+            + jnp.arange(cfg.meta_lanes, dtype=jnp.int32))
+
+
+def _rows_get(cfg: ProtoConfig, plane, lane, blks):
+    """Metadata rows of block blks[...] in cache lane[...]: [..., L]."""
+    return plane[lane[..., None], _row_cols(cfg, blks)]
+
+
+def _rows_put(cfg: ProtoConfig, plane, lane, blks, rows):
+    """Write rows [..., L] back to block blks[...] of cache lane[...];
+    blocks >= n_blocks drop."""
+    return plane.at[lane[..., None], _row_cols(cfg, blks)].set(
+        rows, mode="drop")
 
 
 def _rows_where(g, rows):
@@ -261,27 +280,33 @@ def _rows_any(rows):
     return jnp.any(rows != 0, axis=-1)
 
 
-def plane_scatter_set(plane, lane, b, o):
+def plane_scatter_set(cfg: ProtoConfig, plane, lane, b, o):
     """Bulk flag OR over index triples (the write-combining bulk-store
     path, e.g. worksteal's enqueue scatter).  Triples must be distinct;
     out-of-range b drops.  Packed lanes accumulate by add, which equals OR
     exactly because each (lane, b, o) bit appears at most once."""
+    c = _pl_col(cfg, b, o)
     if PACKED:
-        pattern = jnp.zeros_like(plane).at[
-            lane, b, bitmask.word_index(o)].add(bitmask.word_bit(o),
-                                                mode="drop")
+        pattern = jnp.zeros_like(plane).at[lane, c].add(
+            bitmask.word_bit(o), mode="drop")
         return plane | pattern
-    return plane.at[lane, b, o].set(True, mode="drop")
+    return plane.at[lane, c].set(True, mode="drop")
+
+
+def _plane_bool(st: Store, plane) -> jnp.ndarray:
+    n, nb, w = st.l1.shape
+    rows = plane.reshape(n, nb, -1)
+    return bitmask.unpack(rows, w) if PACKED else rows
 
 
 def wvalid_bool(st: Store) -> jnp.ndarray:
     """Boolean [n_caches, n_blocks, W] view of wvalid (tests/debug)."""
-    return bitmask.unpack(st.wvalid, st.l1.shape[-1]) if PACKED else st.wvalid
+    return _plane_bool(st, st.wvalid)
 
 
 def wdirty_bool(st: Store) -> jnp.ndarray:
     """Boolean [n_caches, n_blocks, W] view of wdirty (tests/debug)."""
-    return bitmask.unpack(st.wdirty, st.l1.shape[-1]) if PACKED else st.wdirty
+    return _plane_bool(st, st.wdirty)
 
 
 # --------------------------------------------------------------------------
@@ -295,17 +320,17 @@ def b_writeback(cfg: ProtoConfig, st: Store, blks, guard) -> Tuple[Store, jnp.nd
     Cross-cache collisions on the same block merge per word, highest cache
     id winning (matches the serial ascending-j order; see module docstring).
     Returns (store', did [n_caches] f32 — 1.0 where any word moved)."""
-    n, nb, W = cfg.n_caches, cfg.n_blocks, cfg.block_words
+    n, nb = cfg.n_caches, cfg.n_blocks
     blks = jnp.asarray(blks, jnp.int32)
     g = jnp.asarray(guard, bool) & (blks >= 0)
     safe = jnp.clip(blks, 0)
-    rows = st.l1[jnp.arange(n), safe]                       # [n, W]
-    dirty_rows = st.wdirty[jnp.arange(n), safe]             # [n, L]
+    lane = jnp.arange(n)
+    rows = st.l1[lane, safe]                                # [n, W]
+    dirty_rows = _rows_get(cfg, st.wdirty, lane, safe)      # [n, L]
     sel = _rows_where(g, dirty_rows)
     idx = jnp.where(g, safe, nb)
     l2 = drain_writeback(st.l2, rows, sel, idx)
-    wdirty = st.wdirty.at[jnp.arange(n), idx].set(
-        dirty_rows & ~sel, mode="drop")
+    wdirty = _rows_put(cfg, st.wdirty, lane, idx, dirty_rows & ~sel)
     did = _rows_any(sel).astype(jnp.float32)
     tot = jnp.sum(did)
     c = st.counters
@@ -330,14 +355,14 @@ def b_drain(cfg: ProtoConfig, st: Store, pos, charge) -> Tuple[Store, jnp.ndarra
     safe = jnp.clip(drained, 0)
     crow = jnp.broadcast_to(jnp.arange(n)[:, None], (n, cap))
     rows = st.l1[crow, safe]                                    # [n, cap, W]
-    dirty_rows = _rows_where(g, st.wdirty[crow, safe])          # [n, cap, L]
+    held = _rows_get(cfg, st.wdirty, crow, safe)                # [n, cap, L]
+    dirty_rows = _rows_where(g, held)
     idx = jnp.where(g, drained, nb)
     # cache-major flatten: later caches override earlier on (racy) collisions
     l2 = drain_writeback(st.l2, rows.reshape(n * cap, W),
-                         dirty_rows.reshape(n * cap, dirty_rows.shape[-1]),
+                         dirty_rows.reshape(n * cap, cfg.meta_lanes),
                          idx.reshape(n * cap))
-    wdirty = st.wdirty.at[crow, idx].set(
-        st.wdirty[crow, safe] & ~dirty_rows, mode="drop")
+    wdirty = _rows_put(cfg, st.wdirty, crow, idx, held & ~dirty_rows)
     did = _rows_any(dirty_rows)                                 # [n, cap]
     n_wb = jnp.sum(did, axis=1).astype(jnp.float32)
     tot = jnp.sum(n_wb)
@@ -356,7 +381,7 @@ def b_invalidate(cfg: ProtoConfig, st: Store, mask) -> Store:
     (§2.2), flash-invalidate, clear LR-TBL and PA-TBL (§4.4)."""
     mask = jnp.asarray(mask, bool)
     st, _ = b_drain(cfg, st, jnp.where(mask, _DRAIN_ALL, INVALID), mask)
-    wvalid = jnp.where(mask[:, None, None],
+    wvalid = jnp.where(mask[:, None],
                        jnp.zeros((), st.wvalid.dtype), st.wvalid)
     # geometry-deriving resets (full_like on the live tables): a custom
     # TableGeometry survives every invalidate
@@ -458,7 +483,7 @@ def b_load(cfg: ProtoConfig, st: Store, active, addrs
     # pre-op valid bit (the L1 hit — also ops.load's OC_HIT/OC_MISS
     # classification) and the plane OR come from one plane_commit pass
     wvalid, _, hit, _ = plane_commit(st.wvalid, st.wdirty, b, o,
-                                     active, None)
+                                     active, None, lanes=cfg.meta_lanes)
     val = jnp.where(hit, st.l1[lane, b, o], st.l2[b, o])
     l1 = st.l1.at[lane, b, o].set(jnp.where(active, val, st.l1[lane, b, o]))
     p = cfg.params
@@ -490,7 +515,8 @@ def b_store_word(cfg: ProtoConfig, st: Store, active, addrs, vals,
     # Pallas kernel on TPU; the was_dirty pre-state it also returns is
     # ops.store's write-combining classification bit)
     wvalid, wdirty, _, _ = plane_commit(st.wvalid, st.wdirty, b, o,
-                                        active, active)
+                                        active, active,
+                                        lanes=cfg.meta_lanes)
     st = st._replace(l1=l1, wvalid=wvalid, wdirty=wdirty)
 
     ft = jnp.broadcast_to(jnp.asarray(force_tail, bool), (n,))
@@ -552,8 +578,8 @@ def b_atomic_l2(cfg, st: Store, active, addrs, expect, new, is_cas
     l2 = st.l2.at[jnp.where(write, b, nb), o].set(
         jnp.where(success, jnp.asarray(new, jnp.int32), cur), mode="drop")
     # local copy of this word is no longer authoritative
-    wvalid = _pl_clear(st.wvalid, lane, b, o, active)
-    wdirty = _pl_clear(st.wdirty, lane, b, o, active)
+    wvalid = _pl_clear(cfg, st.wvalid, lane, b, o, active)
+    wdirty = _pl_clear(cfg, st.wdirty, lane, b, o, active)
     p = cfg.params
     fact = active.astype(jnp.float32)
     c = st.counters

@@ -13,11 +13,12 @@ Two kernels, matching the two fusion surfaces of `ref.py`:
 
   * `plane_commit_pallas` — the wvalid/wdirty plane update of
     `protocol.b_store_word`/`b_load` for every cache lane in one vector
-    pass: the planes are lane-dense [n, nb * K] int32 rows, each lane's
-    target flag is a (column, bit) pair, and a `broadcasted_iota`
-    compare builds the pattern plane that both reads the pre-op bits and
-    ORs the new ones (`core/bitmask.py` semantics; no unpacked plane
-    ever materializes).  Both planes are input/output-aliased.
+    pass: the planes are the Store's lane-dense [n, nb * L] rows (int32
+    inside the kernel), each lane's target flag is a (column, bit) pair,
+    and a `broadcasted_iota` compare builds the pattern plane that both
+    reads the pre-op bits and ORs the new ones (`core/bitmask.py`
+    semantics; no unpacked plane ever materializes).  Both planes are
+    input/output-aliased.
 
 TPU tiling: every block is a whole array, so the (8, 128) block rule
 holds at any n, nb and W, alone and under `jax.vmap` (which adds a
@@ -143,41 +144,40 @@ def _commit_kernel(wv_ref, wd_ref, col_ref, bit_ref, sv_ref, sd_ref,
     wd_out[...] = rd | jnp.where(sd_ref[...] != 0, pattern, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("lanes", "interpret"))
 def plane_commit_pallas(wvalid, wdirty, b, o, set_valid, set_dirty,
-                        *, interpret: bool = False):
+                        *, lanes: int, interpret: bool = False):
     """Fused metadata-plane commit; bitwise `ref.plane_commit_ref`.
 
-    wvalid/wdirty [n, nb, L] uint32 packed or [n, nb, W] bool; b/o [n]
-    i32; set_valid/set_dirty [n] bool.  The planes enter the kernel as
-    int32 [n, nb * K] rows (a bitcast or a 0/1 cast, then a reshape):
-    lane i's flag sits in column b*L + (o >> 5) at bit 1 << (o & 31)
-    (packed, `core/bitmask.py` semantics) or in column b*W + o at bit 1
-    (boolean).  Whole-array VMEM blocks, both planes aliased in place.
-    Returns (wvalid', wdirty', was_valid, was_dirty)."""
-    n, nb, k = wvalid.shape
+    wvalid/wdirty [n, nb * lanes] uint32 packed or bool, the Store's
+    lane-dense planes; b/o [n] i32; set_valid/set_dirty [n] bool.  The
+    planes enter the kernel as int32 rows of the same shape (a bitcast or
+    a 0/1 cast): lane i's flag sits in column b*lanes + (o >> 5) at bit
+    1 << (o & 31) (packed, `core/bitmask.py` semantics) or in column
+    b*lanes + o at bit 1 (boolean).  Whole-array VMEM blocks, both
+    planes aliased in place.  Returns (wvalid', wdirty', was_valid,
+    was_dirty)."""
+    n, cols = wvalid.shape
     packed = wvalid.dtype != jnp.bool_
-    b32 = jnp.clip(jnp.asarray(b, jnp.int32), 0, nb - 1)
+    b32 = jnp.clip(jnp.asarray(b, jnp.int32), 0, cols // lanes - 1)
     o32 = jnp.asarray(o, jnp.int32)
     if packed:
-        col = b32 * k + (o32 >> 5)
+        col = b32 * lanes + (o32 >> 5)
         bit = lax.bitcast_convert_type(
             jnp.uint32(1) << (o32.astype(jnp.uint32) & jnp.uint32(31)),
             jnp.int32)
-        to_rows = lambda p: lax.bitcast_convert_type(p, jnp.int32) \
-            .reshape(n, nb * k)
-        back = lambda r: lax.bitcast_convert_type(r, jnp.uint32) \
-            .reshape(n, nb, k)
+        to_rows = lambda p: lax.bitcast_convert_type(p, jnp.int32)  # noqa: E731
+        back = lambda r: lax.bitcast_convert_type(r, jnp.uint32)  # noqa: E731
     else:
-        col = b32 * k + o32
+        col = b32 * lanes + o32
         bit = jnp.ones((n,), jnp.int32)
-        to_rows = lambda p: p.astype(jnp.int32).reshape(n, nb * k)
-        back = lambda r: (r != 0).reshape(n, nb, k)
-    lane_col = lambda x: jnp.asarray(x, jnp.int32).reshape(n, 1)
+        to_rows = lambda p: p.astype(jnp.int32)  # noqa: E731
+        back = lambda r: r != 0  # noqa: E731
+    lane_col = lambda x: jnp.asarray(x, jnp.int32).reshape(n, 1)  # noqa: E731
     wv2, wd2, wasv, wasd = pl.pallas_call(
         _commit_kernel,
-        out_shape=(jax.ShapeDtypeStruct((n, nb * k), jnp.int32),
-                   jax.ShapeDtypeStruct((n, nb * k), jnp.int32),
+        out_shape=(jax.ShapeDtypeStruct((n, cols), jnp.int32),
+                   jax.ShapeDtypeStruct((n, cols), jnp.int32),
                    jax.ShapeDtypeStruct((n, 1), jnp.int32),
                    jax.ShapeDtypeStruct((n, 1), jnp.int32)),
         input_output_aliases={0: 0, 1: 1},

@@ -44,11 +44,12 @@ def trip_plan(clocks, can_l, can_r, bound, raddr, horizon, *,
                             remote_cap=remote_cap, interpret=interpret)
 
 
-def plane_commit(wvalid, wdirty, b, o, set_valid, set_dirty, *,
+def plane_commit(wvalid, wdirty, b, o, set_valid, set_dirty, *, lanes: int,
                  use_pallas: bool | None = None,
                  interpret: bool | None = None):
     """Fused metadata-plane front-end: pre-op wvalid/wdirty bit reads +
-    per-lane flag OR, one pass over both planes.  Returns
+    per-lane flag OR, one pass over both planes, which are lane-dense
+    [n, nb * lanes] (`lanes` = the layout's columns per block).  Returns
     (wvalid', wdirty', was_valid, was_dirty) — see `ref.plane_commit_ref`.
     `set_dirty=None` is the `b_load` shape: the reference skips the
     wdirty update statically, the kernel ORs an all-False mask — wdirty
@@ -57,10 +58,10 @@ def plane_commit(wvalid, wdirty, b, o, set_valid, set_dirty, *,
         use_pallas = common.use_pallas()
     if not use_pallas:
         return ref.plane_commit_ref(wvalid, wdirty, b, o,
-                                    set_valid, set_dirty)
+                                    set_valid, set_dirty, lanes)
     if interpret is None:
         interpret = common.interpret()
     if set_dirty is None:
         set_dirty = jnp.zeros_like(jnp.asarray(set_valid, bool))
     return plane_commit_pallas(wvalid, wdirty, b, o, set_valid, set_dirty,
-                               interpret=interpret)
+                               lanes=lanes, interpret=interpret)

@@ -94,37 +94,35 @@ def trip_plan_ref(clocks, can_l, can_r, bound, raddr, horizon) -> TripPlan:
     return TripPlan(lmask=lmask, rmask=rmask, wg=wg)
 
 
-def plane_commit_ref(wvalid, wdirty, b, o, set_valid, set_dirty):
+def plane_commit_ref(wvalid, wdirty, b, o, set_valid, set_dirty, lanes):
     """Fused wvalid/wdirty front-end: pre-op bit reads + per-lane flag OR,
     both planes in one pass.
 
-    wvalid/wdirty [n, nb, L] uint32 packed or [n, nb, W] bool; b/o [n] i32
-    per-lane (block, word-offset) targets; set_valid/set_dirty [n] bool OR
-    masks (set_dirty=None skips the wdirty update statically — the
-    `b_load` shape).  Returns (wvalid', wdirty', was_valid, was_dirty):
-    the was_* bits are the PRE-update flags — exactly the OC_HIT/OC_MISS
-    (load) and write-combining (store) classification bits of
-    `ops._l1_state`.  (lane, b) pairs are distinct by construction (lane
-    is the cache id), so the scatters are safe."""
+    wvalid/wdirty [n, nb * lanes], uint32 packed or bool, stored
+    lane-dense: lane w of block b is column b * lanes + w (DESIGN.md §8);
+    b/o [n] i32 per-lane (block, word-offset) targets; set_valid/set_dirty
+    [n] bool OR masks (set_dirty=None skips the wdirty update statically —
+    the `b_load` shape).  Returns (wvalid', wdirty', was_valid,
+    was_dirty): the was_* bits are the PRE-update flags — exactly the
+    OC_HIT/OC_MISS (load) and write-combining (store) classification bits
+    of `ops._l1_state`.  (lane, b) pairs are distinct by construction
+    (lane is the cache id), so the scatters are safe."""
     n = wvalid.shape[0]
     lane = jnp.arange(n)
-    packed = wvalid.dtype != jnp.bool_
-    if packed:
-        w = bitmask.word_index(o)
+    b = jnp.asarray(b, jnp.int32)
+    if wvalid.dtype != jnp.bool_:
+        col = b * lanes + bitmask.word_index(o)
         bit = bitmask.word_bit(o)
-        wv = wvalid[lane, b, w]
-        wd = wdirty[lane, b, w]
-        was_valid = (wv & bit) != 0
-        was_dirty = (wd & bit) != 0
-        mv = jnp.where(jnp.asarray(set_valid, bool), bit, jnp.uint32(0))
-        wvalid = wvalid.at[lane, b, w].set(wv | mv)
-        if set_dirty is not None:
-            md = jnp.where(jnp.asarray(set_dirty, bool), bit, jnp.uint32(0))
-            wdirty = wdirty.at[lane, b, w].set(wd | md)
-        return wvalid, wdirty, was_valid, was_dirty
-    was_valid = wvalid[lane, b, o]
-    was_dirty = wdirty[lane, b, o]
-    wvalid = wvalid.at[lane, b, o].set(was_valid | set_valid)
-    if set_dirty is not None:
-        wdirty = wdirty.at[lane, b, o].set(was_dirty | set_dirty)
-    return wvalid, wdirty, was_valid, was_dirty
+        sv = jnp.where(jnp.asarray(set_valid, bool), bit, jnp.uint32(0))
+        sd = None if set_dirty is None else \
+            jnp.where(jnp.asarray(set_dirty, bool), bit, jnp.uint32(0))
+        test = lambda words: (words & bit) != 0  # noqa: E731
+    else:
+        col = b * lanes + jnp.asarray(o, jnp.int32)
+        sv, sd, test = set_valid, set_dirty, lambda words: words  # noqa: E731
+    wv = wvalid[lane, col]
+    wd = wdirty[lane, col]
+    wvalid = wvalid.at[lane, col].set(wv | sv)
+    if sd is not None:
+        wdirty = wdirty.at[lane, col].set(wd | sd)
+    return wvalid, wdirty, test(wv), test(wd)

@@ -428,8 +428,8 @@ def _enqueue_jit(ws: WSConfig, oacq_b, orel_b, store: P.Store, enq_owner,
     ab, ao = addr // W, addr % W
     st = st._replace(
         l1=st.l1.at[enq_owner, ab, ao].set(chunk_ids + 1, mode="drop"),
-        wvalid=P.plane_scatter_set(st.wvalid, enq_owner, ab, ao),
-        wdirty=P.plane_scatter_set(st.wdirty, enq_owner, ab, ao))
+        wvalid=P.plane_scatter_set(cfg, st.wvalid, enq_owner, ab, ao),
+        wdirty=P.plane_scatter_set(cfg, st.wdirty, enq_owner, ab, ao))
     # record the task-word blocks in the sFIFO (write-combining path)
     first_blk = (locks + QMETA) // W
     no_tail = jnp.zeros((n,), bool)

@@ -186,27 +186,36 @@ def test_plane_commit_kernel_matches_ref(nb, W):
     from repro.kernels.fused_turn.ref import plane_commit_ref
     rng = np.random.default_rng(7)
     n, L = 6, (W + 31) // 32
-    wv = jnp.asarray(rng.integers(0, 2**32, size=(n, nb, L), dtype=np.uint64)
-                     .astype(np.uint32))
-    wd = jnp.asarray(rng.integers(0, 2**32, size=(n, nb, L), dtype=np.uint64)
-                     .astype(np.uint32))
+    # lane-dense [n, nb * L] planes
+    wv = jnp.asarray(rng.integers(0, 2**32, size=(n, nb * L),
+                                  dtype=np.uint64).astype(np.uint32))
+    wd = jnp.asarray(rng.integers(0, 2**32, size=(n, nb * L),
+                                  dtype=np.uint64).astype(np.uint32))
     b = jnp.asarray(rng.integers(0, nb, size=n).astype(np.int32))
     o = jnp.asarray(rng.integers(0, W, size=n).astype(np.int32))
     sv = jnp.asarray(rng.random(n) < 0.7)
     sd = jnp.asarray(rng.random(n) < 0.5)
-    want = plane_commit_ref(wv, wd, b, o, sv, sd)
-    got = plane_commit_pallas(wv, wd, b, o, sv, sd, interpret=True)
+    want = plane_commit_ref(wv, wd, b, o, sv, sd, L)
+    got = plane_commit_pallas(wv, wd, b, o, sv, sd, lanes=L, interpret=True)
     for g, w in zip(got, want):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
-    # cross-check against the boolean-layout reference through unpack
-    unpack = lambda p: np.asarray(bitmask.unpack(jnp.asarray(p), W))  # noqa: E731
-    wvb = jnp.asarray(unpack(wv))
-    wdb = jnp.asarray(unpack(wd))
-    wantb = plane_commit_ref(wvb, wdb, b, o, sv, sd)
-    np.testing.assert_array_equal(unpack(got[0]), np.asarray(wantb[0]))
-    np.testing.assert_array_equal(unpack(got[1]), np.asarray(wantb[1]))
+    # cross-check against the boolean-layout reference (W columns per
+    # block) through unpack
+    unpack = lambda p: np.asarray(  # noqa: E731
+        bitmask.unpack(jnp.asarray(p).reshape(n, nb, L), W))
+    wvb, wdb = jnp.asarray(unpack(wv)), jnp.asarray(unpack(wd))
+    wantb = plane_commit_ref(wvb.reshape(n, nb * W), wdb.reshape(n, nb * W),
+                             b, o, sv, sd, W)
+    flat = lambda p: np.asarray(p).reshape(n, nb, W)  # noqa: E731
+    np.testing.assert_array_equal(unpack(got[0]), flat(wantb[0]))
+    np.testing.assert_array_equal(unpack(got[1]), flat(wantb[1]))
     np.testing.assert_array_equal(np.asarray(got[2]), np.asarray(wantb[2]))
     np.testing.assert_array_equal(np.asarray(got[3]), np.asarray(wantb[3]))
+    # the boolean layout through the kernel
+    gotb = plane_commit_pallas(wvb.reshape(n, nb * W), wdb.reshape(n, nb * W),
+                               b, o, sv, sd, lanes=W, interpret=True)
+    for g, w in zip(gotb, wantb):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
 def test_plane_commit_load_shape_skips_dirty():
@@ -215,19 +224,19 @@ def test_plane_commit_load_shape_skips_dirty():
     from repro.kernels.fused_turn.ref import plane_commit_ref
     rng = np.random.default_rng(9)
     n, nb, L = 4, 4, 1
-    wv = jnp.asarray(rng.integers(0, 2**32, size=(n, nb, L),
+    wv = jnp.asarray(rng.integers(0, 2**32, size=(n, nb * L),
                                   dtype=np.uint64).astype(np.uint32))
-    wd = jnp.asarray(rng.integers(0, 2**32, size=(n, nb, L),
+    wd = jnp.asarray(rng.integers(0, 2**32, size=(n, nb * L),
                                   dtype=np.uint64).astype(np.uint32))
     b = jnp.asarray(np.array([0, 1, 2, 3], np.int32))
     o = jnp.asarray(np.array([0, 5, 13, 15], np.int32))
     sv = jnp.asarray(np.array([True, False, True, True]))
-    wv2, wd2, wasv, wasd = plane_commit_ref(wv, wd, b, o, sv, None)
+    wv2, wd2, wasv, wasd = plane_commit_ref(wv, wd, b, o, sv, None, L)
     np.testing.assert_array_equal(np.asarray(wd2), np.asarray(wd))
     lane = np.arange(n)
-    w = np.asarray(o) >> 5
+    col = np.asarray(b) * L + (np.asarray(o) >> 5)
     bit = np.uint32(1) << (np.asarray(o) & 31)
     np.testing.assert_array_equal(
-        np.asarray(wasv), (np.asarray(wv)[lane, np.asarray(b), w] & bit) != 0)
+        np.asarray(wasv), (np.asarray(wv)[lane, col] & bit) != 0)
     np.testing.assert_array_equal(
-        np.asarray(wasd), (np.asarray(wd)[lane, np.asarray(b), w] & bit) != 0)
+        np.asarray(wasd), (np.asarray(wd)[lane, col] & bit) != 0)
