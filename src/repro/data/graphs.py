@@ -59,22 +59,91 @@ def _to_csr(n: int, src: np.ndarray, dst: np.ndarray, rng, name: str) -> CSRGrap
     return CSRGraph(indptr, v.astype(np.int32), w, name)
 
 
-def collab_like(n: int = 8192, m: int = 6, seed: int = 0) -> CSRGraph:
-    """Preferential-attachment graph (Barabási–Albert style)."""
+class Draws(NamedTuple):
+    words: int       # raw 64-bit PCG64 outputs the draws consumed
+    rejections: int  # 32-bit draws Lemire's rule rejected
+
+
+_MASK32 = 0xFFFFFFFF
+_SLACK_WORDS = 64    # first draw's room for rejections; refills past it
+
+
+def _half_words(bg, count: int):
+    """PCG64's 32-bit draws in `next_uint32` order: the low half of each
+    raw word, then its high half.  Draws `count` words, then more as
+    needed."""
+    while True:
+        w = bg.random_raw(count)
+        yield from np.stack([w & _MASK32, w >> 32], 1).ravel().tolist()
+        count = 1024
+
+
+def _attach(n: int, m: int, seed: int):
+    """Preferential attachment from the raw PCG64 stream (DESIGN.md §15).
+
+    Returns (`repeated`, rng, Draws), bit for bit what a per-node
+    `rng.choice(len(repeated), size=m)` loop gives: numpy decodes each
+    bounded draw from one 32-bit half-word with Lemire's multiply-shift,
+    and the rng is left where that loop would leave it.  `repeated` is
+    range(m), then (target, source) for each edge in order."""
     rng = np.random.default_rng(seed)
-    targets = list(range(m))
-    src, dst = [], []
+    bg = rng.bit_generator
+    if not isinstance(bg, np.random.PCG64):
+        raise TypeError(f"collab graphs decode PCG64's stream, not "
+                        f"{type(bg).__name__}'s")
+    saved = bg.state
     repeated: list[int] = list(range(m))
-    for v in range(m, n):
-        picks = rng.choice(len(repeated), size=m, replace=True)
-        chosen = {repeated[p] for p in picks}
+    v0 = m
+    if m == 1 and n > 1:     # choice(1, ...) draws nothing
+        repeated += [0, 1]
+        v0 = 2
+    draws = m * max(n - v0, 0)
+    nxt = _half_words(bg, -(-draws // 2) + _SLACK_WORDS).__next__
+    rejections = 0
+    per_node = range(m)
+    for v in range(v0, n):
+        k = len(repeated)
+        chosen = set()
+        for _ in per_node:
+            x = nxt() * k
+            if (x & _MASK32) < k:
+                threshold = ((1 << 32) - k) % k
+                while (x & _MASK32) < threshold:
+                    x = nxt() * k
+                    rejections += 1
+            chosen.add(repeated[x >> 32])
         for t in chosen:
-            src.append(v)
-            dst.append(t)
             repeated.append(t)
             repeated.append(v)
-    return _to_csr(n, np.array(src, np.int64), np.array(dst, np.int64), rng,
+    # rewind to what the draws consumed; an odd count leaves a high half
+    # in the bit generator's 32-bit buffer
+    halves = draws + rejections
+    bg.state = saved
+    bg.advance(halves // 2)
+    if halves % 2:
+        high = int(bg.random_raw()) >> 32
+        state = bg.state
+        state["has_uint32"] = 1
+        state["uinteger"] = high
+        bg.state = state
+    return repeated, rng, Draws(-(-halves // 2), rejections)
+
+
+def collab_like(n: int = 8192, m: int = 6, seed: int = 0) -> CSRGraph:
+    """Preferential-attachment graph (Barabási–Albert style)."""
+    repeated, rng, _ = _attach(n, m, seed)
+    edges = np.array(repeated, np.int64)
+    return _to_csr(n, edges[m + 1::2], edges[m::2], rng,
                    f"collab_like_n{n}")
+
+
+def collab_degrees(n: int, m: int, seed: int) -> tuple[np.ndarray, Draws]:
+    """([n] int32 degrees of collab_like(n, m, seed), its Draws).  Each
+    edge (v, t) has t < v and t from a set, so the edges are unique and
+    loop-free: a node's degree is how often `repeated` names it."""
+    repeated, _, draws = _attach(n, m, seed)
+    deg = np.bincount(np.array(repeated[m:], np.int64), minlength=n)
+    return deg.astype(np.int32), draws
 
 
 def router_like(n: int = 8192, seed: int = 1) -> CSRGraph:

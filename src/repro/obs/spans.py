@@ -4,7 +4,8 @@
 profiler session runs, the span lands in the same `.xplane.pb`, on the
 same clock, as the device ops it caused; otherwise entering it is one
 no-op call.  `meta` rides on the host event as stats (an `engine.call`
-span carries its call's sequence number as `seq`).
+span carries its call's sequence number as `seq`); a stat known only at
+the end goes on the entered span through its `set_metadata`.
 
 The registry keeps counters that only exist after a call returns, such as
 an engine call's trip count (`harness.runner`).  It is filled in the order

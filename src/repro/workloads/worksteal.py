@@ -44,7 +44,7 @@ from jax import lax
 from repro.core import ops as O
 from repro.core import protocol as P
 from repro.core import costmodel, sfifo, tables
-from repro.data.graphs import CSRGraph, collab_like
+from repro.data.graphs import CSRGraph, collab_degrees
 from repro.obs import spans
 from repro.workloads import harness
 
@@ -639,16 +639,17 @@ def build(scenario: str, n_agents: int, seed: int = 0, *,
     kw.setdefault("chunk_cap", 8)
     kw.setdefault("n_chunks_max", max(2 * n_agents, 8))
     ws = WSConfig(n_wgs=n_agents, **kw)
-    with spans.span("inputs.graph"):
-        g = collab_like(n=ws.n_chunks_max * ws.chunk_cap // 2, m=3,
-                        seed=1 + seed)
+    with spans.span("inputs.graph") as sp:
+        degrees, draws = collab_degrees(
+            n=ws.n_chunks_max * ws.chunk_cap // 2, m=3, seed=1 + seed)
+        sp.set_metadata(words=draws.words, rejections=draws.rejections)
     wl = build_workload(ws, p, steal)
 
-    frontier = np.arange(g.n, dtype=np.int32)
+    frontier = np.arange(len(degrees), dtype=np.int32)
     with spans.span("inputs.plan"):
         # skewed ownership: agent 0 owns half the chunks, the rest spread
         # round-robin — guarantees the imbalance that makes steals happen
-        plan = _chunk_plan(ws, frontier, g.degrees,
+        plan = _chunk_plan(ws, frontier, degrees,
                            lambda c, sel, nc: (0 if c < nc // 2
                                                else c % ws.n_wgs))
     with spans.span("inputs.store"):

@@ -12,12 +12,14 @@ import numpy as np
 import pytest
 
 from repro import workloads
+from repro.data import graphs
 from repro.obs import spans
 from repro.obs import trace as T
 from repro.workloads import harness
 
 N_AGENTS = 4
 SEED = 3
+GRAPH_N = 32    # build's 8 chunks of 8 tasks at 4 agents, half-full
 INPUT_SPANS = ["inputs.graph", "inputs.plan", "inputs.store",
                "inputs.enqueue"]
 
@@ -128,5 +130,10 @@ def test_profiler_session_holds_input_and_engine_spans(tmp_path):
         for line in plane.lines for e in line.events
         if e.name.startswith(("inputs.", "engine.")))
     assert [name for _, name, _ in events] == INPUT_SPANS + ["engine.call"]
+    # the graph span carries its raw PCG64 words and Lemire rejections
+    _, draws = graphs.collab_degrees(GRAPH_N, 3, SEED + 1)
+    stats = events[0][2]
+    assert (int(stats["words"]), int(stats["rejections"])) \
+        == (draws.words, draws.rejections) and draws.words > 0
     ((_, seq, _), (_, remote_seq, _)) = spans.snapshot()
     assert events[-1][2]["seq"] == seq == remote_seq
