@@ -15,8 +15,8 @@ import math
 import os
 import time
 
-from repro.core.worksteal import WSConfig, run_app, reference_solution
 from repro.data.graphs import collab_like, road_like, router_like
+from repro.workloads.worksteal import WSConfig, run_app, reference_solution
 
 SCENARIOS = ["baseline", "scope_only", "steal_only", "rsp", "srsp"]
 
@@ -140,5 +140,8 @@ def main(n_wgs: int = 16):
 
 
 if __name__ == "__main__":
-    import sys
-    main(int(sys.argv[1]) if len(sys.argv) > 1 else 16)
+    import argparse
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("n_wgs", nargs="?", type=int, default=16,
+                    help="work-groups per scenario run (default 16)")
+    main(ap.parse_args().n_wgs)

@@ -52,8 +52,8 @@ _MEASURE_SNIPPET = r"""
 import json, sys, time
 import numpy as np
 import jax, jax.numpy as jnp
-from repro.core.worksteal import WorkStealSim, WSConfig, SimState
 from repro.data.graphs import collab_like
+from repro.workloads.worksteal import WorkStealSim, WSConfig, SimState
 
 app, scenario, n_wgs, n_chunks, graph_n, iters, engine = (
     sys.argv[1], sys.argv[2], int(sys.argv[3]), int(sys.argv[4]),

@@ -5,8 +5,6 @@ Prints ``name,us_per_call,derived`` CSV rows per the harness contract.
 """
 from __future__ import annotations
 
-import os
-import subprocess
 import sys
 import time
 
@@ -22,15 +20,6 @@ def main():
     section("paper Fig4/5/6 + scaling (work-stealing scenarios)")
     from benchmarks import paper_figs
     paper_figs.main(8 if quick else 16)
-
-    section("sRSP cross-pod selective delta sync (framework layer)")
-    # a simulated 8-device pod axis on host CPUs: the child never needs
-    # the accelerator this process may already hold
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               JAX_PLATFORMS="cpu", PYTHONPATH="src")
-    subprocess.run([sys.executable, "-m", "benchmarks.delta_sync_bench"],
-                   env=env, check=True)
 
     print(f"\n[benchmarks done in {time.time()-t_all:.0f}s]")
 

@@ -1,18 +1,15 @@
-"""Quickstart: the paper's mechanism in 60 lines.
+"""Quickstart: the paper's mechanism in 40 lines.
 
-1. drive the sRSP protocol directly (local release -> remote acquire ->
-   selective flush) and watch the cost counters;
-2. train a tiny LM for a few steps with the public API.
+Drive the sRSP protocol directly (local release -> remote acquire ->
+selective flush) and watch the cost counters.
 
   PYTHONPATH=src python examples/quickstart.py
 """
-import jax
 import jax.numpy as jnp
 
 from repro.core import protocol as P
 from repro.core.costmodel import makespan
 
-# --- 1. the protocol ------------------------------------------------------
 cfg = P.ProtoConfig(n_caches=8, n_words=512)
 store = P.make_store(cfg)
 
@@ -38,14 +35,3 @@ store = P.srsp_remote_release(cfg, store, 5, LOCK, 0)
 store, _ = P.local_acquire(cfg, store, 0, LOCK, 0, 1)
 print(f"promotions={float(store.counters.promotions):3.0f} (exactly 1: "
       f"selectivity per address)")
-
-# --- 2. train a tiny LM ---------------------------------------------------
-from repro.models.registry import get_config
-from repro.train.trainer import TrainConfig, Trainer
-
-cfg_lm = get_config("xlstm-125m", smoke=True)
-trainer = Trainer(cfg_lm, TrainConfig(steps=10, batch=4, seq=64, lr=3e-3,
-                                      log_every=3))
-trainer.run()
-for m in trainer.metrics_log:
-    print(f"step {m['step']:3d}  loss {m['loss']:.4f}  ({m['dt']*1e3:.0f} ms)")

@@ -8,8 +8,9 @@ import argparse
 
 import numpy as np
 
-from repro.core.worksteal import ENGINES, WSConfig, run_app, reference_solution
 from repro.data.graphs import GRAPHS, collab_like, road_like, router_like
+from repro.workloads.worksteal import (ENGINES, WSConfig, reference_solution,
+                                       run_app)
 
 SCENARIOS = ["baseline", "scope_only", "steal_only", "rsp", "srsp"]
 

@@ -65,7 +65,6 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import warnings
 from functools import partial
 from typing import NamedTuple, Tuple
 
@@ -961,19 +960,6 @@ def srsp_remote_release_b(cfg: ProtoConfig, st: Store, active, addrs,
 # protocol bundles
 # --------------------------------------------------------------------------
 
-_DEPRECATION_WARNED: set = set()   # one warning per legacy name per process
-
-
-def _warn_deprecated(old: str, new: str) -> None:
-    if old not in _DEPRECATION_WARNED:
-        _DEPRECATION_WARNED.add(old)
-        warnings.warn(
-            f"Protocol.{old} is deprecated; use Protocol.{new} or the "
-            f"scope-parametric surface in repro.core.ops "
-            f"(acquire/release(..., scope=LOCAL|REMOTE|GLOBAL))",
-            DeprecationWarning, stacklevel=3)
-
-
 @dataclasses.dataclass(frozen=True)
 class Protocol:
     """A registered scope-parametric op table (DESIGN.md §9).
@@ -1001,10 +987,7 @@ class Protocol:
     Instances are looked up by name through the registry
     (`get_protocol` / `protocols()`); `register_protocol` adds one.
     Derived (e.g. fault-injected) protocols come from
-    `workloads.faults.derive` and stay unregistered.
-
-    The pre-redesign `owner_*`/`thief_*` attribute names remain as
-    deprecation shims (one `DeprecationWarning` per name)."""
+    `workloads.faults.derive` and stay unregistered."""
     name: str
     # local (work-group) scope — the cheap common-case ops
     acquire_loc_b: callable   # (cfg, st, active, addrs, expect, new) -> (st, old)
@@ -1039,37 +1022,6 @@ class Protocol:
         several agents in one masked round (DESIGN.md §9)."""
         return self.acquire_rem_b is not None \
             and self.release_rem_b is not None
-
-    # ---- deprecation shims (pre-redesign names) ----
-    @property
-    def owner_acquire(self):
-        _warn_deprecated("owner_acquire", "acquire_loc")
-        return self.acquire_loc
-
-    @property
-    def owner_release(self):
-        _warn_deprecated("owner_release", "release_loc")
-        return self.release_loc
-
-    @property
-    def thief_acquire(self):
-        _warn_deprecated("thief_acquire", "acquire_rem")
-        return self.acquire_rem
-
-    @property
-    def thief_release(self):
-        _warn_deprecated("thief_release", "release_rem")
-        return self.release_rem
-
-    @property
-    def owner_acquire_b(self):
-        _warn_deprecated("owner_acquire_b", "acquire_loc_b")
-        return self.acquire_loc_b
-
-    @property
-    def owner_release_b(self):
-        _warn_deprecated("owner_release_b", "release_loc_b")
-        return self.release_loc_b
 
 
 class UnknownNameError(KeyError, ValueError):

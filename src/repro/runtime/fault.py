@@ -1,23 +1,15 @@
-"""Fault tolerance & straggler mitigation for the training loop.
+"""Straggler and liveness watch for long sweeps (the sweep's CellWatchdog).
 
 * StepTimer — rolling step-time stats; flags straggler steps (z-score over a
-  window).  At cluster scale the same statistic runs per-host and feeds the
-  coordinator's replacement policy; here it drives logging + the grace
-  checkpoint.
-* FaultTolerantRunner — wraps a step callable: on failure it saves an
-  emergency checkpoint and restarts from the latest one, up to max_restarts.
-  Injected failures (tests) exercise the same path a preempted TPU host
-  would.
-* Heartbeat — liveness file other processes / the coordinator can watch.
+  window).
+* Heartbeat — liveness file other processes can watch.
 """
 from __future__ import annotations
 
 import os
 import time
 from collections import deque
-from typing import Callable, Optional
-
-from repro.runtime import checkpoint as CK
+from typing import Optional
 
 
 class StepTimer:
@@ -71,50 +63,3 @@ class Heartbeat:
             os.remove(self.path)
         except OSError:
             pass
-
-
-class FaultTolerantRunner:
-    """run(step_fn) where step_fn(state, step) -> state.  On exception:
-    emergency-checkpoint (if possible), restore latest, continue."""
-
-    def __init__(self, ckpt_dir: str, save_every: int = 100,
-                 max_restarts: int = 3, restore_fn: Callable = None,
-                 save_fn: Callable = None):
-        self.ckpt_dir = ckpt_dir
-        self.save_every = save_every
-        self.max_restarts = max_restarts
-        self.restore_fn = restore_fn or (
-            lambda path, state: CK.restore_checkpoint(path, state))
-        self.save_fn = save_fn or (
-            lambda step, state: CK.save_checkpoint(self.ckpt_dir, step, state))
-        self.restarts = 0
-
-    def run(self, state, step_fn: Callable, n_steps: int, start_step: int = 0,
-            on_step: Callable = None):
-        os.makedirs(self.ckpt_dir, exist_ok=True)
-        step = start_step
-        timer = StepTimer()
-        while step < n_steps:
-            try:
-                timer.start()
-                state = step_fn(state, step)
-                dt, straggler = timer.stop()
-                if on_step:
-                    on_step(step, state, dt, straggler)
-                step += 1
-                if step % self.save_every == 0:
-                    self.save_fn(step, state)
-            except KeyboardInterrupt:
-                raise
-            except Exception as e:  # noqa: BLE001 — node failure surrogate
-                self.restarts += 1
-                if self.restarts > self.max_restarts:
-                    raise RuntimeError(
-                        f"exceeded max_restarts={self.max_restarts}") from e
-                latest = CK.latest_checkpoint(self.ckpt_dir)
-                if latest is None:
-                    # nothing saved yet: restart from the initial state
-                    step = start_step
-                    continue
-                step, state = self.restore_fn(latest, state)
-        return step, state

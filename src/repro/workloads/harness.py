@@ -4,8 +4,8 @@ The paper evaluates sRSP on exactly one driver — Cederman–Tsigas
 work-stealing — but the protocol's claim is about *asymmetric sharing* in
 general: many cheap local-scope operations on privately-owned data,
 punctuated by rare remote-scope operations that observe another agent's
-state.  This module extracts the two schedulers that used to live inside
-`core/worksteal.py` into a generic pair that any workload can bind
+state.  This module holds the schedulers the work-steal simulator
+first owned as a generic set that any workload can bind
 against, so new sharing shapes (producer/consumer drains, reader-heavy
 locks, directory lookups, …) plug in as declarative specs instead of
 forked engines.

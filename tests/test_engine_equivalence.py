@@ -16,10 +16,10 @@ import numpy as np
 import pytest
 
 from repro.core import protocol as P
-from repro.core.worksteal import WSConfig, run_app
 from repro.data.graphs import collab_like, road_like
 from repro.kernels.selective_flush.kernel import drain_writeback_pallas
 from repro.kernels.selective_flush.ref import drain_writeback_ref
+from repro.workloads.worksteal import WSConfig, run_app
 
 WS = WSConfig(n_wgs=4, chunk_cap=32, n_chunks_max=8)
 G = collab_like(n=256, m=3, seed=1)
@@ -115,71 +115,10 @@ def test_trace_on_preserves_results(name):
 
 
 # --------------------------------------------------------------------------
-# fused megakernel engine (ISSUE 8): engine="fused" must be bitwise the
-# batched schedule on every registered workload (DESIGN.md §12)
+# fused megakernel engine (DESIGN.md §12): its equivalence to the serial
+# engine on every workload and scenario is the grid of
+# tests/test_engine_grid_*.py; the trace-on pin stays here
 # --------------------------------------------------------------------------
-
-def _strip_leaves(out):
-    from repro.obs import trace as T
-    return jax.tree.leaves(out._replace(store=T.strip(out.store)))
-
-
-@pytest.mark.parametrize("name", ["producer_consumer", "reader_lock",
-                                  "kv_directory", "worksteal"])
-def test_fused_engine_bitwise_equals_batched(name):
-    """The fused trip (one `trip_plan` + at most one masked local turn)
-    must reproduce the batched engine's final state bitwise — through
-    `trace.strip`, like every cross-engine suite — on all four
-    registered workloads under the paper's protocol."""
-    from repro import workloads
-    from repro.workloads import harness
-    b = workloads.get(name).build("srsp", 4, seed=3)
-    bat = harness.run_batched(b.wl, b.state, *b.ops)
-    b2 = workloads.get(name).build("srsp", 4, seed=3)
-    fus = harness.run_fused(b2.wl, b2.state, *b2.ops)
-    for la, lb in zip(_strip_leaves(bat), _strip_leaves(fus)):
-        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb),
-                                      err_msg=name)
-    assert b2.check(fus)["ok"], name
-    jax.clear_caches()
-
-
-@pytest.mark.slow
-def test_fused_engine_equals_batched_other_scenarios():
-    """The remote-batching capability differs per protocol (rsp has no
-    batched twins; baseline flushes) — the fused restructure must hold
-    on those dispatch paths too."""
-    from repro import workloads
-    from repro.workloads import harness
-    for scen in ("rsp", "baseline"):
-        b = workloads.get("producer_consumer_mc").build(scen, 4, seed=3)
-        bat = harness.run_batched(b.wl, b.state, *b.ops)
-        b2 = workloads.get("producer_consumer_mc").build(scen, 4, seed=3)
-        fus = harness.run_fused(b2.wl, b2.state, *b2.ops)
-        for la, lb in zip(_strip_leaves(bat), _strip_leaves(fus)):
-            np.testing.assert_array_equal(np.asarray(la), np.asarray(lb),
-                                          err_msg=scen)
-        jax.clear_caches()
-
-
-@pytest.mark.slow
-def test_fused_many_equals_batched_many():
-    """The sweep's replica-packed path: `run_fused_many` vs
-    `run_batched_many` (conds lower to selects under vmap — the fused
-    single-local-turn restructure must stay bitwise there too)."""
-    from repro import workloads
-    from repro.workloads import harness
-    mod = workloads.get("kv_directory")
-    b = mod.build("srsp", 4, seed=0)
-    seeds = jnp.arange(2, dtype=jnp.int32)
-    states = jax.vmap(lambda s: mod.init_state(b.wl, s))(seeds)
-    bat = harness.runner_many("batched")(b.wl, states)
-    states2 = jax.vmap(lambda s: mod.init_state(b.wl, s))(seeds)
-    fus = harness.runner_many("fused")(b.wl, states2)
-    for la, lb in zip(_strip_leaves(bat), _strip_leaves(fus)):
-        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
-    jax.clear_caches()
-
 
 def test_fused_engine_trace_on_preserves_results():
     """Observer-effect contract on the fused engine: the trace ring live

@@ -7,14 +7,6 @@ import pytest
 from repro.kernels.selective_flush import selective_flush, selective_apply
 from repro.kernels.selective_flush.ref import (selective_flush_ref,
                                                selective_apply_ref)
-from repro.kernels.rmsnorm import rmsnorm
-from repro.kernels.rmsnorm.ref import rmsnorm_ref
-from repro.kernels.flash_attention import flash_attention
-from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.flash_decode import flash_decode
-from repro.kernels.flash_decode.ref import decode_attention_ref
-from repro.kernels.topk_router import topk_router
-from repro.kernels.topk_router.ref import topk_router_ref
 
 RNG = np.random.default_rng(0)
 
@@ -41,69 +33,6 @@ def test_selective_apply_roundtrip():
                                       np.asarray(bank[i]))
     assert float(jnp.abs(restored).sum()) == pytest.approx(
         float(jnp.abs(bank[jnp.asarray([3, 7, 30])]).sum()), rel=1e-6)
-
-
-@pytest.mark.parametrize("shape", [(2, 7, 128), (1, 256), (3, 5, 11, 64)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_rmsnorm_sweep(shape, dtype):
-    x = jnp.asarray(RNG.normal(size=shape).astype(np.float32)).astype(dtype)
-    w = jnp.asarray(RNG.normal(size=shape[-1:]).astype(np.float32))
-    out = rmsnorm(x, w, use_pallas=True)
-    ref = rmsnorm_ref(x, w)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)),
-                               np.asarray(ref.astype(jnp.float32)),
-                               rtol=tol, atol=tol)
-
-
-@pytest.mark.parametrize("b,hq,hkv,s,d,causal",
-                         [(1, 4, 4, 128, 64, True),
-                          (2, 8, 2, 128, 64, True),
-                          (1, 4, 1, 256, 128, False),
-                          (2, 2, 2, 64, 32, True)])
-def test_flash_attention_sweep(b, hq, hkv, s, d, causal):
-    q = jnp.asarray(RNG.normal(size=(b, hq, s, d)).astype(np.float32))
-    k = jnp.asarray(RNG.normal(size=(b, hkv, s, d)).astype(np.float32))
-    v = jnp.asarray(RNG.normal(size=(b, hkv, s, d)).astype(np.float32))
-    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
-    ref = attention_ref(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4)
-
-
-def test_flash_attention_bf16():
-    q = jnp.asarray(RNG.normal(size=(1, 2, 128, 64))).astype(jnp.bfloat16)
-    k = jnp.asarray(RNG.normal(size=(1, 2, 128, 64))).astype(jnp.bfloat16)
-    v = jnp.asarray(RNG.normal(size=(1, 2, 128, 64))).astype(jnp.bfloat16)
-    out = flash_attention(q, k, v, block_q=64, block_k=64)
-    ref = attention_ref(q, k, v)
-    np.testing.assert_allclose(np.asarray(out.astype(jnp.float32)),
-                               np.asarray(ref.astype(jnp.float32)),
-                               rtol=3e-2, atol=3e-2)
-
-
-@pytest.mark.parametrize("b,hq,hkv,s,d", [(2, 4, 2, 512, 64),
-                                          (1, 8, 8, 1024, 128),
-                                          (3, 4, 1, 256, 32)])
-def test_flash_decode_sweep(b, hq, hkv, s, d):
-    q = jnp.asarray(RNG.normal(size=(b, hq, d)).astype(np.float32))
-    k = jnp.asarray(RNG.normal(size=(b, hkv, s, d)).astype(np.float32))
-    v = jnp.asarray(RNG.normal(size=(b, hkv, s, d)).astype(np.float32))
-    kv_len = jnp.asarray(RNG.integers(1, s + 1, size=b).astype(np.int32))
-    out = flash_decode(q, k, v, kv_len, block_k=128)
-    ref = decode_attention_ref(q, k, v, kv_len)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4)
-
-
-@pytest.mark.parametrize("t,e,k", [(64, 16, 2), (100, 32, 8), (7, 8, 4)])
-def test_topk_router_sweep(t, e, k):
-    logits = jnp.asarray(RNG.normal(size=(t, e)).astype(np.float32))
-    w, i = topk_router(logits, k, use_pallas=True)
-    wr, ir = topk_router_ref(logits, k)
-    np.testing.assert_array_equal(np.asarray(i), np.asarray(ir))
-    np.testing.assert_allclose(np.asarray(w), np.asarray(wr),
-                               rtol=1e-5, atol=1e-6)
 
 
 # --------------------------------------------------------------------------

@@ -16,10 +16,7 @@ Contracts:
    batched remote op (acquire-only or release-only) over lanes with
    pairwise-distinct addresses and disjoint sharer sets is bitwise-equal
    to issuing the scalar op per lane in ascending order (DESIGN.md §9).
-3. **deprecation shims** — the pre-redesign `owner_*`/`thief_*`
-   Protocol attributes still work and emit DeprecationWarning exactly
-   once per name.
-4. **registry ergonomics** — unknown protocol/engine/scenario names
+3. **registry ergonomics** — unknown protocol/engine/scenario names
    raise with the list of registered names.
 
 Plus the multi-consumer producer/consumer equivalence: co-scheduled
@@ -27,8 +24,6 @@ remote turns (a TRUE multi-lane remote batch) reproduce the serial
 engine bitwise on every leaf except the PA-TBL age/content metadata,
 where the batch is a documented cost-conservative superset (§9).
 """
-import warnings
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -201,34 +196,7 @@ def test_same_cu_remote_acquire_one_hot_equals_scalar():
 
 
 # --------------------------------------------------------------------------
-# 3. deprecation shims
-# --------------------------------------------------------------------------
-
-def test_deprecation_shims_warn_exactly_once():
-    proto = P.get_protocol("srsp")
-    P._DEPRECATION_WARNED.discard("owner_acquire_b")
-    with warnings.catch_warnings(record=True) as seen:
-        warnings.simplefilter("always")
-        assert proto.owner_acquire_b is proto.acquire_loc_b
-        assert proto.owner_acquire_b is proto.acquire_loc_b  # second access
-    dep = [w for w in seen if issubclass(w.category, DeprecationWarning)]
-    assert len(dep) == 1, [str(w.message) for w in dep]
-    assert "acquire_loc_b" in str(dep[0].message)
-
-
-def test_deprecation_shims_route_to_scoped_table():
-    proto = P.get_protocol("srsp")
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore", DeprecationWarning)
-        assert proto.owner_acquire is proto.acquire_loc
-        assert proto.owner_release is proto.release_loc
-        assert proto.thief_acquire is proto.acquire_rem
-        assert proto.thief_release is proto.release_rem
-        assert proto.owner_release_b is proto.release_loc_b
-
-
-# --------------------------------------------------------------------------
-# 4. registry ergonomics
+# 3. registry ergonomics
 # --------------------------------------------------------------------------
 
 def test_unknown_names_raise_with_registered_list():

@@ -12,8 +12,8 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core.worksteal import WSConfig, run_app, reference_solution
 from repro.data.graphs import collab_like, road_like
+from repro.workloads.worksteal import WSConfig, run_app, reference_solution
 
 WS = WSConfig(n_wgs=4, chunk_cap=32, n_chunks_max=16)
 G = collab_like(n=384, m=3, seed=1)
